@@ -37,6 +37,9 @@ def main() -> None:
         os.environ.setdefault("REPRO_BENCH_ROUNDS", SMOKE_ROUNDS)
 
     from benchmarks import data_bench, kernels_bench, tables
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     if args.tables:
         want = args.tables.split(",")

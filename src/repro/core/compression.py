@@ -392,7 +392,7 @@ def code_domain_aggregate(
 
     ``topk`` planes aggregate in the payload domain instead: each
     client's (value, index) pairs — exactly the wire payload — go
-    through one weighted segment-bucketed scatter-add
+    through one weighted XLA scatter-add
     (``wire_pack.topk_scatter_add``) into the dense mean, so the slow
     path's K rematerialized dense fp32 trees (and their K-deep
     tensordot) never exist. Dropped clients carry weight n_k = 0, so

@@ -442,17 +442,16 @@ def _client_update_stage(
     args = (params, round_batch, jnp.arange(K), base_key, round_idx)
     if sharding is None:
         return stage(*args)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     sharding.check_clients(K)
     ax = sharding.axis
-    return shard_map(
+    return jax.shard_map(
         stage,
         mesh=sharding.mesh,
         in_specs=(P(), P(ax), P(ax), P(), P()),
         out_specs=(P(ax), P(ax), P(ax)),
-        check_rep=False,
+        check_vma=False,
     )(*args)
 
 
@@ -479,7 +478,6 @@ def _sharded_code_fastpath(
     rides the same client-axis sharding in and out — its update is
     purely local to each shard's clients, so no extra collectives
     appear. Returns (wbar replicated, losses (K,), n_k (K,), ef')."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     K = jax.tree.leaves(round_batch)[0].shape[0]
@@ -498,12 +496,12 @@ def _sharded_code_fastpath(
             wbar = code_domain_aggregate(plane.compression, deltas, n_k, pm, cks, axis=ax)
             return wbar, losses, n_k
 
-        wbar, losses, n_k = shard_map(
+        wbar, losses, n_k = jax.shard_map(
             stage,
             mesh=sharding.mesh,
             in_specs=(P(), P(ax), P(ax), P(ax), P(ax), P(), P()),
             out_specs=(P(), P(ax), P(ax)),
-            check_rep=False,
+            check_vma=False,
         )(params, round_batch, jnp.arange(K), pmask, ckeys, base_key, round_idx)
         return wbar, losses, n_k, None
 
@@ -514,12 +512,12 @@ def _sharded_code_fastpath(
         )
         return wbar, losses, n_k, e2
 
-    return shard_map(
+    return jax.shard_map(
         stage_ef,
         mesh=sharding.mesh,
         in_specs=(P(), P(ax), P(ax), P(ax), P(ax), P(), P(), P(ax)),
         out_specs=(P(), P(ax), P(ax), P(ax)),
-        check_rep=False,
+        check_vma=False,
     )(params, round_batch, jnp.arange(K), pmask, ckeys, base_key, round_idx, ef)
 
 

@@ -63,7 +63,7 @@ class FederatedTask:
     evaluate: Callable  # (params, corpus, n) -> {"quality", "quality_hard"}
     adapt_batch: Optional[Callable] = None  # engine batch -> model batch
     client_quality: Optional[Callable] = None  # (params, (C, n, ...) batch) -> (C,)
-    make_corpus: Callable = None  # (seed) -> corpus
+    make_corpus: Callable = None  # (seed, **CorpusConfig overrides) -> corpus
 
     @functools.cached_property
     def loss_fn(self) -> Callable:
@@ -84,14 +84,17 @@ class FederatedTask:
 # ---------------------------------------------------------------- corpus
 
 
-def default_corpus(seed: int = 0):
-    """The shared container-scale speaker corpus (same shape every
-    task trains on — and bit-identical to the historical
-    ``launch.train.tiny_asr_setup`` corpus)."""
+def default_corpus(seed: int = 0, *, vocab: int = 64, feat_dim: int = 16, **overrides):
+    """The shared 48-speaker corpus. At its defaults it is the
+    container-scale corpus every task trains on (bit-identical to the
+    historical ``launch.train.tiny_asr_setup`` corpus); published-width
+    tasks pass their config's ``vocab``/``feat_dim``. ``overrides``
+    reach ``CorpusConfig`` (e.g. ``max_label_len``)."""
     from repro.data import make_speaker_corpus
 
     return make_speaker_corpus(
-        num_speakers=48, vocab_size=64, feat_dim=16, mean_utterances=24.0, seed=seed
+        num_speakers=48, vocab_size=vocab, feat_dim=feat_dim, mean_utterances=24.0,
+        seed=seed, **overrides,
     )
 
 
@@ -311,11 +314,21 @@ def task_for_config(cfg, name: Optional[str] = None) -> FederatedTask:
     )
 
 
-def arch_task(arch_id: str) -> FederatedTask:
-    """A task from the ``--arch`` registry's smoke config."""
+def arch_task(arch_id: str, full: bool = False) -> FederatedTask:
+    """A task from the ``--arch`` registry: its smoke config on the
+    container-scale corpus, or with ``full`` its published widths on a
+    corpus built at the config's own vocab and feature dim (``feat_dim``
+    where the config has one)."""
     from repro.configs import get_arch
 
-    return task_for_config(get_arch(arch_id).make_smoke_config(), name=arch_id)
+    arch = get_arch(arch_id)
+    if not full:
+        return task_for_config(arch.make_smoke_config(), name=arch_id)
+    cfg = arch.make_config()
+    corpus = functools.partial(
+        default_corpus, vocab=cfg.vocab, feat_dim=getattr(cfg, "feat_dim", 16)
+    )
+    return dataclasses.replace(task_for_config(cfg, name=arch_id), make_corpus=corpus)
 
 
 # ----------------------------------------------------- named registry

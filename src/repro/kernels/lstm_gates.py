@@ -4,7 +4,10 @@ The per-step LSTM cell after the matmuls is four sigmoids/tanhs and
 two multiplies over (B, H) — on TPU a chain of small VPU ops whose
 HBM round-trips between unfused HLOs dominate the step at decode
 batch sizes. The kernel fuses them in one VMEM-resident pass.
-Gates layout: (B, 4, H) [i | f | g | o]; grid tiles (B, H).
+Gates layout: (B, 4, H) [i | f | g | o]; each block carries the whole
+(small) batch and the grid tiles H only, so every block's last two
+dims are either full array dims or multiples of 128 lanes — the shape
+Mosaic accepts for bf16 gates and an f32 cell.
 
 ``lstm_gates_fused_vjp`` adds a custom-VJP wrapper whose backward is a
 second fused kernel: it saves only (gates, c) — the matmul outputs the
@@ -27,17 +30,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _gate(g_ref, k: int):
+    return g_ref[:, k, :].astype(jnp.float32)  # (B, th) of block (B, 4, th)
+
+
 def _kernel(g_ref, c_ref, h_out_ref, c_out_ref):
-    g = g_ref[0].astype(jnp.float32)  # (4, th)... block (1, 4, th)
-    c = c_ref[0].astype(jnp.float32)  # (th,)  block (1, th)
-    i = jax.nn.sigmoid(g[0])
-    f = jax.nn.sigmoid(g[1] + 1.0)
-    gg = jnp.tanh(g[2])
-    o = jax.nn.sigmoid(g[3])
+    c = c_ref[...].astype(jnp.float32)  # (B, th)
+    i = jax.nn.sigmoid(_gate(g_ref, 0))
+    f = jax.nn.sigmoid(_gate(g_ref, 1) + 1.0)
+    gg = jnp.tanh(_gate(g_ref, 2))
+    o = jax.nn.sigmoid(_gate(g_ref, 3))
     c_new = f * c + i * gg
     h_new = o * jnp.tanh(c_new)
-    h_out_ref[0] = h_new.astype(h_out_ref.dtype)
-    c_out_ref[0] = c_new.astype(c_out_ref.dtype)
+    h_out_ref[...] = h_new.astype(h_out_ref.dtype)
+    c_out_ref[...] = c_new.astype(c_out_ref.dtype)
+
+
+def _specs(B: int, th: int):
+    """(gates, state) block specs for a grid over H tiles: a (B, 4, th)
+    gates block and a (B, th) block of h / c / their cotangents."""
+    return (pl.BlockSpec((B, 4, th), lambda hi: (0, 0, hi)),
+            pl.BlockSpec((B, th), lambda hi: (0, hi)))
 
 
 def lstm_gates_fused(
@@ -51,17 +64,12 @@ def lstm_gates_fused(
     assert H % th == 0, (H, th)
     g3 = gates.reshape(B, 4, H)
 
+    gspec, sspec = _specs(B, th)
     h_new, c_new = pl.pallas_call(
         _kernel,
-        grid=(B, H // th),
-        in_specs=[
-            pl.BlockSpec((1, 4, th), lambda b, hi: (b, 0, hi)),
-            pl.BlockSpec((1, th), lambda b, hi: (b, hi)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, th), lambda b, hi: (b, hi)),
-            pl.BlockSpec((1, th), lambda b, hi: (b, hi)),
-        ],
+        grid=(H // th,),
+        in_specs=[gspec, sspec],
+        out_specs=[sspec, sspec],
         out_shape=[
             jax.ShapeDtypeStruct((B, H), gates.dtype),
             jax.ShapeDtypeStruct((B, H), c.dtype),
@@ -72,21 +80,20 @@ def lstm_gates_fused(
 
 
 def _bwd_kernel(g_ref, c_ref, dh_ref, dcn_ref, dg_ref, dc_ref):
-    g = g_ref[0].astype(jnp.float32)  # (4, th)
-    c = c_ref[0].astype(jnp.float32)  # (th,)
-    dh = dh_ref[0].astype(jnp.float32)
-    dcn = dcn_ref[0].astype(jnp.float32)
-    i = jax.nn.sigmoid(g[0])
-    f = jax.nn.sigmoid(g[1] + 1.0)
-    gg = jnp.tanh(g[2])
-    o = jax.nn.sigmoid(g[3])
+    c = c_ref[...].astype(jnp.float32)  # (B, th)
+    dh = dh_ref[...].astype(jnp.float32)
+    dcn = dcn_ref[...].astype(jnp.float32)
+    i = jax.nn.sigmoid(_gate(g_ref, 0))
+    f = jax.nn.sigmoid(_gate(g_ref, 1) + 1.0)
+    gg = jnp.tanh(_gate(g_ref, 2))
+    o = jax.nn.sigmoid(_gate(g_ref, 3))
     t = jnp.tanh(f * c + i * gg)  # tanh(c_new), recomputed in VMEM
     dc = dcn + dh * o * (1.0 - t * t)
-    dg_ref[0, 0] = (dc * gg * i * (1.0 - i)).astype(dg_ref.dtype)
-    dg_ref[0, 1] = (dc * c * f * (1.0 - f)).astype(dg_ref.dtype)
-    dg_ref[0, 2] = (dc * i * (1.0 - gg * gg)).astype(dg_ref.dtype)
-    dg_ref[0, 3] = (dh * t * o * (1.0 - o)).astype(dg_ref.dtype)
-    dc_ref[0] = (dc * f).astype(dc_ref.dtype)
+    dg_ref[:, 0, :] = (dc * gg * i * (1.0 - i)).astype(dg_ref.dtype)
+    dg_ref[:, 1, :] = (dc * c * f * (1.0 - f)).astype(dg_ref.dtype)
+    dg_ref[:, 2, :] = (dc * i * (1.0 - gg * gg)).astype(dg_ref.dtype)
+    dg_ref[:, 3, :] = (dh * t * o * (1.0 - o)).astype(dg_ref.dtype)
+    dc_ref[...] = (dc * f).astype(dc_ref.dtype)
 
 
 def lstm_gates_bwd_fused(gates, c, dh, dc_next, *, th: int = 256, interpret: bool = False):
@@ -100,19 +107,12 @@ def lstm_gates_bwd_fused(gates, c, dh, dc_next, *, th: int = 256, interpret: boo
     assert H % th == 0, (H, th)
     g3 = gates.reshape(B, 4, H)
 
+    gspec, sspec = _specs(B, th)
     dg3, dc_prev = pl.pallas_call(
         _bwd_kernel,
-        grid=(B, H // th),
-        in_specs=[
-            pl.BlockSpec((1, 4, th), lambda b, hi: (b, 0, hi)),
-            pl.BlockSpec((1, th), lambda b, hi: (b, hi)),
-            pl.BlockSpec((1, th), lambda b, hi: (b, hi)),
-            pl.BlockSpec((1, th), lambda b, hi: (b, hi)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 4, th), lambda b, hi: (b, 0, hi)),
-            pl.BlockSpec((1, th), lambda b, hi: (b, hi)),
-        ],
+        grid=(H // th,),
+        in_specs=[gspec, sspec, sspec, sspec],
+        out_specs=[gspec, sspec],
         out_shape=[
             jax.ShapeDtypeStruct((B, 4, H), gates.dtype),
             jax.ShapeDtypeStruct((B, H), c.dtype),

@@ -134,16 +134,15 @@ def topk_unpack_ref(values, idx, n: int):
 # ------------------------------------------------- in-kernel PRNG oracle
 # jax's threefry2x32 PRNG, restated elementwise so the quantize kernels
 # can draw each element's uniform from its flat position alone — no
-# (n,)-shaped uniform field ever streams through HBM. With the repo's
-# pinned threefry (non-partitionable) impl, jax.random.uniform(key, (n,))
-# hashes counters iota(n) split into halves (x0 = counts[:half],
-# x1 = counts[half:], half = (n+1)//2; odd n pads one zero counter) and
-# concatenates the two output lanes. Position j therefore owns lane 0 of
-# pair (j, j+half) when j < half (the pad turns the missing counter into
-# 0), else lane 1 of pair (j-half, j). ``threefry_uniform_at`` computes
-# exactly that, so it equals the streamed draw bit for bit by
-# construction — the tolerance-free parity contract of the keyed
-# quantize kernels (tests/test_wire_pack.py sweeps even/odd n).
+# (n,)-shaped uniform field ever streams through HBM. Under jax's
+# partitionable threefry layout (the default since jax 0.5), the 32-bit
+# draw at flat position j hashes the 64-bit counter j as the word pair
+# (hi(j), lo(j)) and XORs the two output lanes, so each element's bits
+# depend on its position alone, never on the draw size.
+# ``threefry_random_bits_at`` computes exactly that for j < 2**32, so it
+# equals jax.random.bits / jax.random.uniform bit for bit — the
+# tolerance-free parity contract of the keyed quantize kernels
+# (tests/test_wire_pack.py sweeps even/odd n).
 
 _THREEFRY_C = 0x1BD11BDA
 _THREEFRY_ROT = ((13, 15, 26, 6), (17, 29, 16, 24))
@@ -170,17 +169,12 @@ def threefry2x32_pair(k0, k1, c0, c1):
     return x0, x1
 
 
-def threefry_random_bits_at(k0, k1, pos, n: int):
-    """Random uint32 at flat position(s) ``pos`` of a size-``n`` draw —
-    elementwise jax.random.bits(key, (n,))."""
-    half = (n + 1) // 2
+def threefry_random_bits_at(k0, k1, pos):
+    """Random uint32 at flat position(s) ``pos`` (< 2**32) of any draw —
+    elementwise jax.random.bits(key, (n,)) for every n > pos."""
     pos = pos.astype(jnp.uint32)
-    lo = pos < jnp.uint32(half)
-    pair = jnp.where(lo, pos, pos - jnp.uint32(half))
-    c1 = pair + jnp.uint32(half)
-    c1 = jnp.where(c1 < jnp.uint32(n), c1, jnp.uint32(0))
-    o0, o1 = threefry2x32_pair(k0, k1, pair, c1)
-    return jnp.where(lo, o0, o1)
+    o0, o1 = threefry2x32_pair(k0, k1, jnp.zeros_like(pos), pos)
+    return o0 ^ o1
 
 
 def bits_to_uniform(bits):
@@ -198,7 +192,7 @@ def threefry_uniform_ref(key_data, n: int):
     k0 = key_data[0].astype(jnp.uint32)
     k1 = key_data[1].astype(jnp.uint32)
     pos = jnp.arange(n, dtype=jnp.uint32)
-    return bits_to_uniform(threefry_random_bits_at(k0, k1, pos, n))
+    return bits_to_uniform(threefry_random_bits_at(k0, k1, pos))
 
 
 def topk_scatter_add_ref(values, idx, weights, n: int):

@@ -9,13 +9,10 @@ real packed buffer, not an accounting fiction:
   sign-extended back on unpack — a pure VPU bit-twiddle pass.
 - ``dequantize``: intN codes x fp32 scale -> f32, fused in one
   VMEM-resident pass (the server-side unpack of every intN payload).
-- ``topk_unpack``: scatter a (value, index) payload into the dense
-  tensor. Two variants: the original serial kernel (all k stores into
-  one VMEM-resident block) and the *segmented* scatter
-  (``topk_unpack_segmented_pallas``) that sorts the payload by index
-  once, computes per-segment bounds with a searchsorted, and lets each
-  grid cell store only its own contiguous slice — small VMEM blocks,
-  pipelined output windows, and no serial pass over the whole tensor.
+- ``topk_unpack`` / ``topk_scatter_add``: scatter a (value, index)
+  payload into the dense tensor. These are XLA scatters on every
+  backend, not Pallas kernels: a Pallas scatter needs one-element
+  stores at dynamic lane offsets, which Mosaic refuses.
 - ``quantize_pack``: the *fused* uplink client kernel — grid-divide by
   the (shared or per-tensor) scale, clamp into the code grid,
   stochastic-round against a uniform field, and (for int4) nibble-pack
@@ -24,7 +21,7 @@ real packed buffer, not an accounting fiction:
   4-byte scales can be max-reduced across the client axis first
   (shared-scale negotiation: exact code-domain sums).
 
-Each kernel has a jnp oracle in ``ref.py`` (the parity target,
+Each Pallas kernel has a jnp oracle in ``ref.py`` (the parity target,
 interpret=True on CPU) and a public auto-dispatch wrapper (Pallas on
 TPU, the oracle as the CPU production path — same convention as the
 model kernels). Pack->unpack is the identity on codes by construction,
@@ -245,9 +242,9 @@ def quantize_pack4_pallas(x, scale, u, *, tile: int = _TILE, interpret: bool = F
 # each element's flat position (repro.kernels.ref.threefry_random_bits_at
 # — plain uint32 jnp ops, so the identical 20-round hash runs on every
 # backend). The (n,)-sized uniform field never exists in HBM, and the
-# draw equals jax.random.uniform(key, (n,)) bit for bit, which keeps the
-# packed plane's tolerance-free parity with the historical streamed-field
-# path (the PR 5 contract).
+# draw equals jax.random.uniform(key, (n,)) bit for bit under jax's
+# partitionable threefry layout, which keeps the packed plane's
+# tolerance-free parity with the streamed-field path.
 
 
 def _iota_pos(tile: int):
@@ -256,23 +253,23 @@ def _iota_pos(tile: int):
     return base + jax.lax.broadcasted_iota(jnp.uint32, (1, tile), 1)
 
 
-def _keyed_uniform(k_ref, pos, n: int):
+def _keyed_uniform(k_ref, pos):
     k0 = k_ref[0, 0]
     k1 = k_ref[0, 1]
-    return ref.bits_to_uniform(ref.threefry_random_bits_at(k0, k1, pos, n))
+    return ref.bits_to_uniform(ref.threefry_random_bits_at(k0, k1, pos))
 
 
-def _quantize_keyed_kernel(levels: float, n: int, tile: int, x_ref, s_ref, k_ref, out_ref):
-    u = _keyed_uniform(k_ref, _iota_pos(tile), n)
+def _quantize_keyed_kernel(levels: float, tile: int, x_ref, s_ref, k_ref, out_ref):
+    u = _keyed_uniform(k_ref, _iota_pos(tile))
     y = jnp.clip(x_ref[...] / s_ref[0, 0], -levels, levels)
     lo = jnp.floor(y)
     out_ref[...] = (lo + (u < (y - lo)).astype(jnp.float32)).astype(jnp.int8)
 
 
-def _quantize_pack4_keyed_kernel(n: int, tile: int, xe_ref, xo_ref, s_ref, k_ref, out_ref):
+def _quantize_pack4_keyed_kernel(tile: int, xe_ref, xo_ref, s_ref, k_ref, out_ref):
     pair = _iota_pos(tile)
-    ue = _keyed_uniform(k_ref, pair * jnp.uint32(2), n)
-    uo = _keyed_uniform(k_ref, pair * jnp.uint32(2) + jnp.uint32(1), n)
+    ue = _keyed_uniform(k_ref, pair * jnp.uint32(2))
+    uo = _keyed_uniform(k_ref, pair * jnp.uint32(2) + jnp.uint32(1))
     s = s_ref[0, 0]
 
     def q(x_ref, u):
@@ -297,7 +294,7 @@ def quantize_with_scale_keyed_pallas(
     sspec = pl.BlockSpec((1, 1), lambda i: (0, 0))
     kspec = pl.BlockSpec((1, 2), lambda i: (0, 0))
     out = pl.pallas_call(
-        functools.partial(_quantize_keyed_kernel, levels, n, tile),
+        functools.partial(_quantize_keyed_kernel, levels, tile),
         grid=(npad // tile,),
         in_specs=[spec, sspec, kspec],
         out_specs=spec,
@@ -324,7 +321,7 @@ def quantize_pack4_keyed_pallas(x, scale, key_data, *, tile: int = _TILE, interp
     sspec = pl.BlockSpec((1, 1), lambda i: (0, 0))
     kspec = pl.BlockSpec((1, 2), lambda i: (0, 0))
     out = pl.pallas_call(
-        functools.partial(_quantize_pack4_keyed_kernel, n, tile),
+        functools.partial(_quantize_pack4_keyed_kernel, tile),
         grid=(nbp // tile,),
         in_specs=[spec, spec, sspec, kspec],
         out_specs=spec,
@@ -334,145 +331,15 @@ def quantize_pack4_keyed_pallas(x, scale, key_data, *, tile: int = _TILE, interp
     return out[0, :nb]
 
 
-# ------------------------------------------------------------- topk unpack
-
-
-def _topk_unpack_kernel(v_ref, i_ref, out_ref):
-    out_ref[...] = jnp.zeros_like(out_ref)
-
-    def body(j, carry):
-        idx = pl.load(i_ref, (slice(0, 1), pl.ds(j, 1)))[0, 0]
-        val = pl.load(v_ref, (slice(0, 1), pl.ds(j, 1)))
-        pl.store(out_ref, (slice(0, 1), pl.ds(idx, 1)), val)
-        return carry
-
-    jax.lax.fori_loop(0, i_ref.shape[1], body, 0)
-
-
-def topk_unpack_pallas(values, idx, n: int, *, interpret: bool = False):
-    """(k,) f32 values + (k,) int32 flat indices -> dense (n,) f32.
-
-    The serial variant: every store lands in one n-wide VMEM block.
-    Kept as the small-n fallback and the parity reference for the
-    segmented kernel below."""
-    out = pl.pallas_call(
-        _topk_unpack_kernel,
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
-        interpret=interpret,
-    )(values[None, :], idx[None, :])
-    return out[0]
-
-
-def _topk_unpack_seg_kernel(seg: int, b_ref, v_ref, i_ref, out_ref):
-    """One grid cell owns output segment [pid*seg, (pid+1)*seg): the
-    payload arrives sorted by index, so this cell's entries are the
-    contiguous slice b[pid] .. b[pid+1] of the payload — a dynamic-
-    bound loop over *its own* entries only, instead of every cell (or
-    one serial pass) scanning all k."""
-    pid = pl.program_id(0)
-    base = pid * seg
-    start = pl.load(b_ref, (slice(0, 1), pl.ds(pid, 1)))[0, 0]
-    end = pl.load(b_ref, (slice(0, 1), pl.ds(pid + 1, 1)))[0, 0]
-    out_ref[...] = jnp.zeros_like(out_ref)
-
-    def body(j, carry):
-        idx = pl.load(i_ref, (slice(0, 1), pl.ds(j, 1)))[0, 0]
-        val = pl.load(v_ref, (slice(0, 1), pl.ds(j, 1)))
-        pl.store(out_ref, (slice(0, 1), pl.ds(idx - base, 1)), val)
-        return carry
-
-    jax.lax.fori_loop(start, end, body, 0)
-
-
-def topk_unpack_segmented_pallas(values, idx, n: int, *, seg: int = 2048, interpret: bool = False):
-    """Segmented (grid-parallel) top-k scatter: sort the (value, index)
-    payload by index, searchsorted the segment boundaries, and give
-    each grid cell one seg-wide output window plus the payload slice
-    that lands in it. VMEM holds one segment (not the whole tensor),
-    output windows pipeline, and total store work stays O(k)."""
-    k = values.shape[0]
-    seg = min(seg, max(n, 1))
-    npad = n + (-n) % seg
-    nseg = npad // seg
-    order = jnp.argsort(idx)
-    sv, si = values[order], idx[order]
-    bounds = jnp.searchsorted(si, jnp.arange(nseg + 1, dtype=jnp.int32) * seg).astype(jnp.int32)
-    out = pl.pallas_call(
-        functools.partial(_topk_unpack_seg_kernel, seg),
-        grid=(nseg,),
-        in_specs=[
-            pl.BlockSpec((1, nseg + 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, seg), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, npad), jnp.float32),
-        interpret=interpret,
-    )(bounds[None, :], sv[None, :], si[None, :])
-    return out[0, :n]
-
-
-# -------------------------------------------------------- topk scatter-add
-
-
-def _topk_scatter_add_seg_kernel(seg: int, b_ref, v_ref, i_ref, out_ref):
-    """Segmented weighted scatter-ADD: like the segmented unpack, each
-    grid cell owns one seg-wide output window and walks only its own
-    contiguous (sorted-by-index) payload slice — but read-add-store, so
-    duplicate indices (the same coordinate picked by several clients)
-    accumulate instead of overwriting. The serial walk within a segment
-    is what makes the accumulation race-free."""
-    pid = pl.program_id(0)
-    base = pid * seg
-    start = pl.load(b_ref, (slice(0, 1), pl.ds(pid, 1)))[0, 0]
-    end = pl.load(b_ref, (slice(0, 1), pl.ds(pid + 1, 1)))[0, 0]
-    out_ref[...] = jnp.zeros_like(out_ref)
-
-    def body(j, carry):
-        idx = pl.load(i_ref, (slice(0, 1), pl.ds(j, 1)))[0, 0]
-        val = pl.load(v_ref, (slice(0, 1), pl.ds(j, 1)))
-        cur = pl.load(out_ref, (slice(0, 1), pl.ds(idx - base, 1)))
-        pl.store(out_ref, (slice(0, 1), pl.ds(idx - base, 1)), cur + val)
-        return carry
-
-    jax.lax.fori_loop(start, end, body, 0)
-
-
-def topk_scatter_add_pallas(values, idx, n: int, *, seg: int = 2048, interpret: bool = False):
-    """(m,) f32 pre-weighted values + (m,) int32 flat indices (possibly
-    duplicated across clients) -> dense (n,) f32 accumulated sum."""
-    m = values.shape[0]
-    seg = min(seg, max(n, 1))
-    npad = n + (-n) % seg
-    nseg = npad // seg
-    order = jnp.argsort(idx)
-    sv, si = values[order], idx[order]
-    bounds = jnp.searchsorted(si, jnp.arange(nseg + 1, dtype=jnp.int32) * seg).astype(jnp.int32)
-    out = pl.pallas_call(
-        functools.partial(_topk_scatter_add_seg_kernel, seg),
-        grid=(nseg,),
-        in_specs=[
-            pl.BlockSpec((1, nseg + 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, seg), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, npad), jnp.float32),
-        interpret=interpret,
-    )(bounds[None, :], sv[None, :], si[None, :])
-    return out[0, :n]
-
-
 # ---------------------------------------------------- public auto-dispatch
 # Pallas on TPU; the jnp oracle is the CPU production path (interpret
-# mode is for tests only — same convention as repro.kernels.ops).
-# Every dispatch constant lives in the tuning registry
-# (repro.profile.tuner): 'wire_pack.dispatch' overrides the
-# backend choice per device, 'wire_pack.topk_seg_min_n' /
-# 'wire_pack.topk_seg_size' are the PR 5 segmented-scatter thresholds,
-# re-measurable on this machine via `python -m repro.profile.tuner
-# --autotune topk`. The lazy import keeps the kernel layer free of any
-# import-order coupling (tuner is stdlib-only at module level).
+# mode is for tests only — same convention as repro.kernels.ops). The
+# tuning registry's 'wire_pack.dispatch' knob (repro.profile.tuner)
+# overrides the backend choice per device. The lazy import keeps the
+# kernel layer free of any import-order coupling (tuner is stdlib-only
+# at module level). The top-k scatters are XLA scatters on every
+# backend: Mosaic refuses the one-element dynamic lane stores a Pallas
+# scatter needs.
 
 
 def _dispatch() -> tuple:
@@ -510,18 +377,9 @@ def dequantize(codes, scale):
 
 
 def topk_unpack(values, idx, n: int):
-    from repro.profile.tuner import get_knob
-
-    use_ref, interpret = _dispatch()
-    if use_ref:
-        return ref.topk_unpack_ref(values, idx, n)
-    # below the measured crossover the serial kernel's single block is
-    # cheaper than sorting the payload + a multi-cell grid
-    if n < int(get_knob("wire_pack.topk_seg_min_n")):
-        return topk_unpack_pallas(values, idx, n, interpret=interpret)
-    return topk_unpack_segmented_pallas(
-        values, idx, n, seg=int(get_knob("wire_pack.topk_seg_size")), interpret=interpret
-    )
+    """(k,) values + (k,) int32 flat indices -> dense (n,) f32: an XLA
+    scatter on every backend."""
+    return ref.topk_unpack_ref(values, idx, n)
 
 
 def quantize_with_scale(x, scale, u, bits: int):
@@ -596,16 +454,6 @@ def quantize_pack_keyed(x, scale, key_data, bits: int):
 def topk_scatter_add(values, idx, weights, n: int):
     """Aggregate stacked top-k payloads in the code domain: values
     (K, k) f32, idx (K, k) int32, weights (K,) -> dense (n,) f32
-    weighted sum. Duplicate coordinates accumulate. Dispatch follows the
-    same registry knobs as ``topk_unpack`` (the segmented kernel shares
-    its segment-size crossover)."""
-    from repro.profile.tuner import get_knob
-
-    use_ref, interpret = _dispatch()
-    if use_ref:
-        return ref.topk_scatter_add_ref(values, idx, weights, n)
-    flat_vals = (weights[:, None] * values.astype(jnp.float32)).reshape(-1)
-    flat_idx = idx.reshape(-1)
-    return topk_scatter_add_pallas(
-        flat_vals, flat_idx, n, seg=int(get_knob("wire_pack.topk_seg_size")), interpret=interpret
-    )
+    weighted sum. Duplicate coordinates accumulate. An XLA scatter-add
+    on every backend, like ``topk_unpack``."""
+    return ref.topk_scatter_add_ref(values, idx, weights, n)

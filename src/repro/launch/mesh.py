@@ -7,28 +7,25 @@ init; tests and benches must keep seeing 1 CPU device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist from jax 0.5; older releases
-    default every axis to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def _auto_mesh(shape, axes):
+    """jax.make_mesh with every axis Auto (the compiler partitions);
+    jax.make_mesh alone defaults to Explicit axes."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs of the pjit code paths."""
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_federated_mesh(clients: int = 1):
@@ -48,7 +45,7 @@ def make_federated_mesh(clients: int = 1):
             f"XLA_FLAGS=--xla_force_host_platform_device_count={clients} "
             "before jax initializes"
         )
-    return compat_make_mesh((clients,), ("clients",))
+    return _auto_mesh((clients,), ("clients",))
 
 
 # TPU v5e hardware constants for the roofline (per chip).

@@ -1210,6 +1210,9 @@ def main():
     add_plan_args(ap)
     add_client_eval_args(ap)
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     run_grid(args.grid, rounds=args.rounds, smoke=args.smoke, seed=args.seed,
              out=args.out, pad_steps=args.pad_steps, check=args.check,
              prune_budget=args.prune_budget, prune_axis=args.prune_axis,

@@ -5,14 +5,20 @@ paper's RNN-T, the enc-dec/LM/MoE/RWKV zoo tasks, or the
 keyword-spotting tiny model — on the synthetic speaker-split corpus,
 with the paper's knobs (data limit, FVN, server LR schedule), CFMQ
 accounting per round, and the optional per-client evaluation plane.
-On this container it runs the reduced configs on CPU; the same driver
-pjits onto the production mesh when one is available.
+Each round is one jitted step on the default device; ``--mesh-clients
+N`` shards the round's client axis over N devices of a 1-D ``clients``
+mesh (``core.fedavg.ClientSharding``). Nothing else is partitioned.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.train --task asr-rnnt --rounds 40
     PYTHONPATH=src python -m repro.launch.train --task keyword \
         --population 1000000 --clients 32
-    PYTHONPATH=src python -m repro.launch.train --arch rnnt-librispeech ...
+    PYTHONPATH=src python -m repro.launch.train --preset arch --arch rnnt-librispeech ...
+    PYTHONPATH=src python -m repro.launch.train --preset full --arch rnnt-librispeech ...
+
+``--preset arch`` runs the ``--arch`` entry's smoke config on the
+container-scale corpus; ``--preset full`` runs its published widths on
+a corpus built at the config's own feature dim and vocab.
 
 The task carries the model AND its eval contract, so this module has
 no model-specific code: quality is WER, perplexity or error rate
@@ -59,6 +65,7 @@ from repro.launch.cli import (
     add_scale_args,
     plan_kwargs,
 )
+from repro.launch.compile_cache import use_compile_cache
 
 
 def tiny_asr_setup(seed: int = 0):
@@ -319,7 +326,9 @@ def main():
     ap.add_argument("--task", default=None, choices=available_tasks(),
                     help="a registered FederatedTask (model + eval metric); "
                          "overrides --preset/--arch")
-    ap.add_argument("--preset", default="tiny", choices=["tiny", "arch"])
+    ap.add_argument("--preset", default="tiny", choices=["tiny", "arch", "full"],
+                    help="tiny: the container-scale asr-rnnt task; arch: the "
+                         "--arch smoke config; full: the --arch published widths")
     ap.add_argument("--arch", default="rnnt-librispeech")
     ap.add_argument("--rounds", type=int, default=40)
     ap.add_argument("--clients", type=int, default=8)
@@ -344,14 +353,15 @@ def main():
                          "eval section timers, keyed by the engine's "
                          "structural key + device fingerprint)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.task is not None:
         task = get_task(args.task)
     elif args.preset == "tiny":
         task = get_task("asr-rnnt")
     else:
-        task = arch_task(args.arch)
-    corpus = default_corpus(0)
+        task = arch_task(args.arch, full=args.preset == "full")
+    corpus = task.make_corpus(0)
     if args.population:
         from repro.data import VirtualPopulation
 

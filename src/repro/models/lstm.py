@@ -56,13 +56,15 @@ def _fused_tile(h: int):
 
 
 def _lstm_gates_dispatch(gates: jnp.ndarray, c: jnp.ndarray):
-    """TPU: the Pallas fused kernel with its fused custom-VJP backward
-    (the cell's backward dominates the federated round's per-client
-    scan). CPU and non-tiling hidden sizes: the jnp reference — same
-    math, XLA-fused, and what every parity test pins."""
-    th = _fused_tile(c.shape[-1]) if gates.ndim == 2 else None
-    if th is None or jax.default_backend() == "cpu":
+    """Off-CPU: the Pallas fused kernel with its fused custom-VJP
+    backward (the cell's backward dominates the federated round's
+    per-client scan). CPU: the jnp reference — same math, XLA-fused,
+    and what every parity test pins. The one shape gate is H % 128: a
+    hidden size that does not tile into 128-lane blocks keeps the
+    reference on every backend."""
+    if jax.default_backend() == "cpu" or c.shape[-1] % 128:
         return lstm_gates(gates, c)
+    th = _fused_tile(c.shape[-1])
     from repro.kernels.lstm_gates import lstm_gates_fused_vjp
 
     return lstm_gates_fused_vjp(gates, c, th=th)

@@ -9,6 +9,7 @@ EncDecConfig / VLMConfig / RNNTConfig).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -56,7 +57,8 @@ class ModelBundle:
     init_cache: Optional[Callable] = None   # (batch, seq_len, ring=False) -> cache
 
     def param_count(self, params) -> int:
-        return sum(int(jnp.size(x)) for x in jax.tree.leaves(params))
+        # shapes only: counts concrete params and eval_shape'd ones alike
+        return sum(math.prod(x.shape) for x in jax.tree.leaves(params))
 
 
 # ------------------------------------------------------------- rwkv model

@@ -226,13 +226,6 @@ def load_traces(dirpath: str, kind: Optional[str] = None) -> list[dict]:
 # -------------------------------------------------------- measurement
 
 
-def _block(x):
-    try:
-        return jax.block_until_ready(x)
-    except Exception:
-        return x
-
-
 def measure_interleaved_min(
     fns: dict[str, Callable], reps: Optional[int] = None, warmup: int = 1
 ) -> dict[str, float]:
@@ -251,12 +244,12 @@ def measure_interleaved_min(
     names = list(fns)
     for _ in range(max(warmup, 1)):
         for name in names:
-            _block(fns[name]())
+            jax.block_until_ready(fns[name]())
     best = {name: float("inf") for name in names}
     for r in range(reps):
         order = names[r % len(names) :] + names[: r % len(names)]
         for name in order:
             t0 = time.perf_counter()
-            _block(fns[name]())
+            jax.block_until_ready(fns[name]())
             best[name] = min(best[name], time.perf_counter() - t0)
     return best

@@ -2,10 +2,9 @@
 threshold, with measured-on-this-device overrides, plus the sweep-grid
 pruner.
 
-Before this module, dispatch constants (the 4096-element
-``topk_unpack`` serial-vs-segmented cutoff, bench interleave rep
-counts, the Pallas-vs-ref backend choice) were hard-coded from one
-machine's benchmarks. Here every such constant is a named *knob* with
+Before this module, dispatch constants (bench interleave rep counts,
+the Pallas-vs-ref backend choice) were hard-coded from one machine's
+benchmarks. Here every such constant is a named *knob* with
 a documented default; call sites read them through :func:`get_knob`,
 and per-device measured overrides persist to ``results/tuning.json``
 keyed by the trace plane's device fingerprint — so a new backend tunes
@@ -26,8 +25,8 @@ time.
 CLI::
 
     PYTHONPATH=src python -m repro.profile.tuner --show
-    PYTHONPATH=src python -m repro.profile.tuner --set wire_pack.topk_seg_min_n 8192
-    PYTHONPATH=src python -m repro.profile.tuner --autotune topk
+    PYTHONPATH=src python -m repro.profile.tuner --set lstm.scan_min_seq 32
+    PYTHONPATH=src python -m repro.profile.tuner --autotune lstm
 """
 
 from __future__ import annotations
@@ -52,17 +51,6 @@ class Knob:
 
 
 KNOBS: dict[str, Knob] = {
-    "wire_pack.topk_seg_min_n": Knob(
-        4096,
-        "Output elements above which topk_unpack dispatches the segmented "
-        "grid-parallel scatter instead of the serial single-block kernel "
-        "(PR 5 measured the crossover at 4096 on one TPU host).",
-    ),
-    "wire_pack.topk_seg_size": Knob(
-        2048,
-        "Segment length of the segmented top-k scatter (one grid cell "
-        "owns one segment of the output).",
-    ),
     "wire_pack.dispatch": Knob(
         "auto",
         "Pallas-vs-ref backend choice for the wire kernels: 'auto' picks "
@@ -277,61 +265,6 @@ def get_knob(name: str):
 # ----------------------------------------------------------------------
 
 
-def autotune_topk_dispatch(
-    reg: Optional[TuningRegistry] = None,
-    sizes=(1024, 2048, 4096, 8192, 16384, 32768),
-    frac: float = 0.05,
-    reps: int = 5,
-    persist: bool = True,
-    log=print,
-) -> int:
-    """Measure serial vs segmented ``topk_unpack`` Pallas kernels over
-    ``sizes`` and persist the first size where the segmented scatter
-    wins as ``wire_pack.topk_seg_min_n``.
-
-    On CPU both candidates run in interpret mode, so the measured
-    crossover validates the machinery rather than the production
-    dispatch (CPU dispatch always takes the jnp oracle); on TPU this is
-    the real PR 5 threshold, re-measured for the local chip.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import wire_pack
-    from repro.profile.trace import measure_interleaved_min
-
-    reg = reg or registry()
-    interpret = jax.default_backend() == "cpu"
-    crossover = None
-    for n in sizes:
-        k = max(1, int(frac * n))
-        key = jax.random.PRNGKey(n)
-        values = jax.random.normal(key, (k,), jnp.float32)
-        idx = jnp.arange(k, dtype=jnp.int32) * (n // k)
-        serial = jax.jit(
-            lambda v, i: wire_pack.topk_unpack_pallas(v, i, n, interpret=interpret)
-        )
-        seg = jax.jit(
-            lambda v, i: wire_pack.topk_unpack_segmented_pallas(
-                v, i, n, seg=reg.get("wire_pack.topk_seg_size"), interpret=interpret
-            )
-        )
-        t = measure_interleaved_min(
-            {"serial": lambda: serial(values, idx), "segmented": lambda: seg(values, idx)},
-            reps=reps,
-        )
-        log(
-            f"[tuner] topk_unpack n={n}: serial {t['serial'] * 1e6:.1f}us "
-            f"segmented {t['segmented'] * 1e6:.1f}us"
-        )
-        if crossover is None and t["segmented"] < t["serial"]:
-            crossover = n
-    chosen = crossover if crossover is not None else max(sizes) * 2
-    reg.set_override("wire_pack.topk_seg_min_n", chosen, persist=persist)
-    log(f"[tuner] wire_pack.topk_seg_min_n <- {chosen} (device {reg.device_key})")
-    return chosen
-
-
 def autotune_lstm_scan(
     reg: Optional[TuningRegistry] = None,
     seq_lens=(4, 8, 16, 32, 64, 128),
@@ -399,7 +332,6 @@ def autotune_lstm_scan(
 
 
 AUTOTUNERS: dict[str, Callable] = {
-    "topk": autotune_topk_dispatch,
     "lstm": autotune_lstm_scan,
 }
 

@@ -116,7 +116,8 @@ def test_rnnt_joint_custom_vjp_matches_ref_grad():
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,H,th", [(4, 512, 256), (1, 128, 128), (8, 1024, 512)])
+@pytest.mark.parametrize("B,H,th", [(4, 512, 256), (1, 128, 128), (8, 1024, 512),
+                                    (4, 1152, 128)])
 def test_lstm_gates_sweep(B, H, th, dtype):
     gates = _rand((B, 4 * H), dtype)
     c = _rand((B, H), jnp.float32)
@@ -127,7 +128,8 @@ def test_lstm_gates_sweep(B, H, th, dtype):
     np.testing.assert_allclose(np.asarray(c1), np.asarray(c2), atol=TOL[dtype])
 
 
-@pytest.mark.parametrize("B,H,th", [(4, 512, 256), (1, 128, 128), (3, 256, 256)])
+@pytest.mark.parametrize("B,H,th", [(4, 512, 256), (1, 128, 128), (3, 256, 256),
+                                    (8, 1152, 128)])
 def test_lstm_gates_fused_backward_matches_autodiff(B, H, th):
     """The fused custom-VJP backward == jax.grad through the jnp
     reference cell, for both output cotangents (h feeds the next

@@ -217,7 +217,7 @@ def test_wire_cost_profile():
 # ----------------------------------------------------------------------
 
 def test_tuner_defaults_and_unknown_knob(tmp_registry):
-    assert tuner.get_knob("wire_pack.topk_seg_min_n") == 4096
+    assert tuner.get_knob("lstm.scan_min_seq") == 16
     assert tuner.get_knob("wire_pack.dispatch") == "auto"
     with pytest.raises(KeyError, match="unknown tuning knob"):
         tuner.get_knob("nope.missing")
@@ -226,15 +226,15 @@ def test_tuner_defaults_and_unknown_knob(tmp_registry):
 
 
 def test_tuner_override_persist_round_trip(tmp_registry):
-    tmp_registry.set_override("wire_pack.topk_seg_min_n", 1024, persist=True)
+    tmp_registry.set_override("lstm.scan_min_seq", 1024, persist=True)
     tmp_registry.set_coefficients("analytic", {"flops": 1e-10}, persist=True)
     # a fresh registry over the same file sees both, keyed per device
     reloaded = tuner.TuningRegistry(path=tmp_registry.path)
-    assert reloaded.get("wire_pack.topk_seg_min_n") == 1024
+    assert reloaded.get("lstm.scan_min_seq") == 1024
     assert reloaded.get_coefficients("analytic") == {"flops": 1e-10}
     assert reloaded.get_coefficients("hlo") is None
-    reloaded.clear_override("wire_pack.topk_seg_min_n")
-    assert reloaded.get("wire_pack.topk_seg_min_n") == 4096
+    reloaded.clear_override("lstm.scan_min_seq")
+    assert reloaded.get("lstm.scan_min_seq") == 16
     doc = json.load(open(tmp_registry.path))
     assert trace.device_key() in doc["devices"]
 
@@ -252,7 +252,7 @@ def test_tuner_corrupt_file_falls_back_to_defaults(tmp_path):
     path = tmp_path / "tuning.json"
     path.write_text("{broken")
     reg = tuner.TuningRegistry(path=str(path))
-    assert reg.get("wire_pack.topk_seg_min_n") == 4096
+    assert reg.get("lstm.scan_min_seq") == 16
 
 
 def test_tuner_env_path(tmp_path, monkeypatch):
@@ -283,24 +283,6 @@ def test_wire_pack_dispatch_modes(tmp_registry):
         packed = wire_pack.nibble_pack(codes)
         out = wire_pack.nibble_unpack(packed, 32)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(codes))
-
-
-def test_wire_pack_topk_threshold_knob(tmp_registry):
-    """Lowering topk_seg_min_n must flip topk_unpack onto the segmented
-    kernel without changing results."""
-    from repro.kernels import wire_pack
-
-    n = 96
-    vals = jnp.asarray(np.linspace(1.0, 4.0, 8), jnp.float32)
-    idx = jnp.asarray(np.arange(0, 64, 8), jnp.int32)
-    baseline = np.asarray(wire_pack.topk_unpack(vals, idx, n))
-    tmp_registry.set_override("wire_pack.topk_seg_min_n", 16)
-    tmp_registry.set_override("wire_pack.topk_seg_size", 32)
-    segmented = np.asarray(wire_pack.topk_unpack(vals, idx, n))
-    np.testing.assert_array_equal(segmented, baseline)
-    dense = np.zeros(n, np.float32)
-    dense[np.asarray(idx)] = np.asarray(vals)
-    np.testing.assert_array_equal(segmented, dense)
 
 
 # ----------------------------------------------------------------------

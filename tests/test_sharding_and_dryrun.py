@@ -87,11 +87,11 @@ DRYRUN_SNIPPET = textwrap.dedent("""
     from repro.configs import get_arch
     from repro.core import FederatedPlan, init_server_state, make_round_step
     from repro.core.fedavg import server_state_specs
-    from repro.launch.mesh import compat_make_mesh
     from repro.launch.sharding import make_param_specs, sanitize_specs, named
     from repro.models import build_model
 
-    mesh = compat_make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     arch = get_arch("qwen3-8b")
     cfg = arch.make_smoke_config()
     bundle = build_model(cfg)

@@ -1,0 +1,111 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e.
+
+No chip is attached: the TPU compiler installed with jax compiles for a
+v5e that is only described (``topologies.get_topology_desc``), which
+refuses what interpret mode never checks — block shapes that do not
+tile, and more VMEM than a kernel may use. Shapes are the published
+widths of ``rnnt-librispeech`` (H = 1152) and one ``w_hh`` leaf
+(n = 1152 * 4608) for the wire kernels.
+
+The topology is described inside a module-scoped fixture, never at
+import, so every pytest worker collects the same tests and only the
+worker running this file loads the TPU library. The persistent
+compilation cache is off around these compiles: an entry compiled for
+a described chip cannot be read back without one.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import wire_pack
+from repro.kernels.lstm_gates import lstm_gates_bwd_fused, lstm_gates_fused, lstm_gates_fused_vjp
+from repro.models.lstm import _fused_tile
+
+H = 1152
+N = H * 4 * H  # one encoder w_hh
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("B", [1, 4, 8])
+def test_lstm_gates_forward_compiles(one_chip, B):
+    gates = _spec((B, 4 * H), jnp.bfloat16, one_chip)
+    c = _spec((B, H), jnp.float32, one_chip)
+    _assert_kernel(lambda g, c_: lstm_gates_fused(g, c_, th=_fused_tile(H)), gates, c)
+
+
+@pytest.mark.parametrize("B", [1, 4, 8])
+def test_lstm_gates_backward_compiles(one_chip, B):
+    gates = _spec((B, 4 * H), jnp.bfloat16, one_chip)
+    c = _spec((B, H), jnp.float32, one_chip)
+    dh = _spec((B, H), jnp.bfloat16, one_chip)
+    _assert_kernel(
+        lambda g, c_, dh_, dc: lstm_gates_bwd_fused(g, c_, dh_, dc, th=_fused_tile(H)),
+        gates, c, dh, c,
+    )
+
+
+def test_lstm_gates_vjp_compiles_vmapped_over_clients(one_chip):
+    """The round vmaps the cell over K clients: the batched kernels,
+    forward and backward, as the local step's gradient runs them."""
+    K, B = 8, 4
+    gates = _spec((K, B, 4 * H), jnp.bfloat16, one_chip)
+    c = _spec((K, B, H), jnp.float32, one_chip)
+
+    def loss(g, c_):
+        h, cn = lstm_gates_fused_vjp(g, c_, th=_fused_tile(H))
+        return jnp.sum(h.astype(jnp.float32)) + jnp.sum(cn)
+
+    _assert_kernel(jax.vmap(jax.grad(loss, argnums=(0, 1))), gates, c)
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_keyed_quantize_compiles(one_chip, bits):
+    x = _spec((N,), jnp.float32, one_chip)
+    scale = _spec((), jnp.float32, one_chip)
+    key = _spec((2,), jnp.uint32, one_chip)
+    if bits == 8:
+        fn = lambda x_, s, k: wire_pack.quantize_with_scale_keyed_pallas(x_, s, k, 8)  # noqa: E731
+    else:
+        fn = wire_pack.quantize_pack4_keyed_pallas
+    _assert_kernel(fn, x, scale, key)
+
+
+def test_dequantize_compiles(one_chip):
+    codes = _spec((N,), jnp.int8, one_chip)
+    scale = _spec((), jnp.float32, one_chip)
+    _assert_kernel(wire_pack.dequantize_pallas, codes, scale)
+
+
+def test_nibble_unpack_compiles(one_chip):
+    packed = _spec((N // 2,), jnp.int8, one_chip)
+    _assert_kernel(lambda p: wire_pack.nibble_unpack_pallas(p, N), packed)

@@ -64,43 +64,35 @@ def test_dequantize_kernel_matches_ref(n):
                                   np.asarray(ref.dequantize_ref(codes, scale)))
 
 
-@pytest.mark.parametrize("n,k", [(8, 1), (64, 5), (256, 32), (1, 1)])
-def test_topk_unpack_kernel_matches_ref(n, k):
+def _dense_scatter(vals, idx, n):
+    dense = np.zeros((n,), np.float32)
+    dense[np.asarray(idx)] = np.asarray(vals)
+    return dense
+
+
+@pytest.mark.parametrize("n,k", [(8, 1), (64, 5), (256, 32), (1, 1), (100, 11),
+                                 (4096, 200), (16, 16), (2048, 64), (513, 7),
+                                 (65536, 3277)])
+def test_topk_unpack_matches_manual(n, k):
+    """The public top-k unpack (an XLA scatter on every backend) equals
+    a host-side dense scatter — including a full payload (k == n), the
+    size-1 degenerate and a wide leaf."""
     rng = np.random.default_rng(k * 100 + n)
     vals = jnp.asarray(rng.normal(size=(k,)), jnp.float32)
     idx = jnp.asarray(rng.choice(n, size=k, replace=False), jnp.int32)
-    out = wire_pack.topk_unpack_pallas(vals, idx, n, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out),
-                                  np.asarray(ref.topk_unpack_ref(vals, idx, n)))
-
-
-@pytest.mark.parametrize("n,k,seg", [(64, 5, 16), (256, 32, 64), (100, 11, 32),
-                                     (4096, 200, 1024), (16, 16, 16), (1, 1, 8)])
-def test_topk_unpack_segmented_matches_ref(n, k, seg):
-    """The grid-parallel segmented scatter: sorted payload + per-segment
-    searchsorted bounds must reproduce the serial scatter exactly —
-    including entries straddling segment boundaries, a full payload
-    (k == n) and the size-1 degenerate."""
-    rng = np.random.default_rng(k * 7 + n)
-    vals = jnp.asarray(rng.normal(size=(k,)), jnp.float32)
-    idx = jnp.asarray(rng.choice(n, size=k, replace=False), jnp.int32)
-    out = wire_pack.topk_unpack_segmented_pallas(vals, idx, n, seg=seg,
-                                                 interpret=True)
+    out = wire_pack.topk_unpack(vals, idx, n)
     assert out.shape == (n,)
-    np.testing.assert_array_equal(np.asarray(out),
-                                  np.asarray(ref.topk_unpack_ref(vals, idx, n)))
+    np.testing.assert_array_equal(np.asarray(out), _dense_scatter(vals, idx, n))
 
 
-def test_topk_unpack_segmented_boundary_indices():
-    """Entries exactly on segment edges (0, seg-1, seg, n-1) land in the
-    right cells."""
-    n, seg = 128, 32
+def test_topk_unpack_boundary_indices():
+    """Entries at the first and last positions and across 32-wide
+    boundaries land where they belong."""
+    n = 128
     idx = jnp.asarray([0, 31, 32, 63, 64, 127], jnp.int32)
     vals = jnp.arange(1.0, 7.0, dtype=jnp.float32)
-    out = wire_pack.topk_unpack_segmented_pallas(vals, idx, n, seg=seg,
-                                                 interpret=True)
-    np.testing.assert_array_equal(np.asarray(out),
-                                  np.asarray(ref.topk_unpack_ref(vals, idx, n)))
+    out = wire_pack.topk_unpack(vals, idx, n)
+    np.testing.assert_array_equal(np.asarray(out), _dense_scatter(vals, idx, n))
 
 
 # ----------------------------------------------- fused quantize -> pack
@@ -329,6 +321,20 @@ def test_threefry_uniform_oracle_bit_parity(seed, n):
     np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
 
 
+@pytest.mark.parametrize("n", [1, 2, 65, 513, 4097])
+def test_threefry_bits_oracle_matches_jax_random_bits(n):
+    """The elementwise hash equals jax.random.bits under the installed
+    jax's threefry layout, and a position's bits do not depend on the
+    draw size (a size-n draw is a prefix of a larger one)."""
+    key = jax.random.PRNGKey(7 * n)
+    k0, k1 = key[0].astype(jnp.uint32), key[1].astype(jnp.uint32)
+    mine = ref.threefry_random_bits_at(k0, k1, jnp.arange(n, dtype=jnp.uint32))
+    theirs = jax.random.bits(key, (n,), jnp.uint32)
+    np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
+    longer = jax.random.bits(key, (n + 3,), jnp.uint32)
+    np.testing.assert_array_equal(np.asarray(mine), np.asarray(longer[:n]))
+
+
 def test_threefry_uniform_oracle_bit_parity_after_fold_in():
     """The production keys are per-client/per-leaf fold_in derivations —
     the oracle must track the full key-derivation chain."""
@@ -387,7 +393,7 @@ def test_keyed_pack_matches_streamed_pack(bits, n):
 def test_topk_scatter_add_matches_manual(K, k, n):
     """Weighted payload scatter-add: duplicates accumulate, weights
     scale per client — checked against a host-side loop, on the jnp
-    oracle and the segmented Pallas kernel."""
+    oracle and the public dispatcher."""
     rng = np.random.default_rng(K * n + k)
     vals = jnp.asarray(rng.normal(size=(K, k)), jnp.float32)
     idx = jnp.asarray(rng.integers(0, n, (K, k)), jnp.int32)
@@ -399,10 +405,8 @@ def test_topk_scatter_add_matches_manual(K, k, n):
     got = ref.topk_scatter_add_ref(vals, idx, w, n)
     np.testing.assert_allclose(np.asarray(got), manual.astype(np.float32),
                                rtol=1e-6, atol=1e-6)
-    flat = (w[:, None] * vals).reshape(-1)
-    got_k = wire_pack.topk_scatter_add_pallas(flat, idx.reshape(-1), n,
-                                              seg=16, interpret=True)
-    np.testing.assert_allclose(np.asarray(got_k), manual.astype(np.float32),
+    got_d = wire_pack.topk_scatter_add(vals, idx, w, n)
+    np.testing.assert_allclose(np.asarray(got_d), manual.astype(np.float32),
                                rtol=1e-6, atol=1e-6)
 
 
