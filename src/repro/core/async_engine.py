@@ -133,7 +133,9 @@ def _async_round_body(
 ):
     """One wave of the buffered-async engine (one jitted graph):
     client deltas -> cohort -> payload pipeline -> time-ordered arrival
-    stream -> buffer inserts -> staleness-discounted flushes.
+    stream -> buffer inserts -> staleness-discounted flushes. The stages
+    carry the sync body's ``jax.named_scope``s (``client``, ``server``
+    and its children; see ``fedavg._fedavg_round_body``).
 
     With ``sharding`` only the client-update stage shards (the heavy
     per-client local training); the arrival stream is inherently
@@ -143,31 +145,35 @@ def _async_round_body(
     K = jax.tree.leaves(round_batch)[0].shape[0]
     ckey, qkey, akey, xkey = _plane_keys(base_key, state.round_idx)
 
-    round_batch, pmask = _apply_cohort(plane, ckey, round_batch)
+    with jax.named_scope("server"), jax.named_scope("cohort"):
+        round_batch, pmask = _apply_cohort(plane, ckey, round_batch)
 
-    deltas, losses, n_k = _client_update_stage(
-        loss_fn, client_opt, sigma_fn, base_key, state.params, round_batch,
-        state.round_idx, sharding,
-    )
+    with jax.named_scope("client"):
+        deltas, losses, n_k = _client_update_stage(
+            loss_fn, client_opt, sigma_fn, base_key, state.params, round_batch,
+            state.round_idx, sharding,
+        )
 
-    ckeys = _client_key_fanout(plane, qkey, K)
-    deltas, ef, cmask, stale = _delta_payload_stage(
-        plane, deltas, state.ef, pmask, ckeys, xkey, state.stale
-    )
+    with jax.named_scope("server"), jax.named_scope("uplink"):
+        ckeys = _client_key_fanout(plane, qkey, K)
+        deltas, ef, cmask, stale = _delta_payload_stage(
+            plane, deltas, state.ef, pmask, ckeys, xkey, state.stale
+        )
 
     # Arrival order: participants by simulated upload time, then
     # non-participants (time +inf — they never upload). The argsort is
     # stable, so the zero-spread parity configuration (all times equal)
     # keeps the identity permutation and stays bit-compatible with the
     # sync engine's client order.
-    times = latency_fn(_latency_key(base_key, state.round_idx), K)
-    order = jnp.argsort(jnp.where(pmask > 0, times, jnp.inf))
-    arr = (
-        jax.tree.map(lambda d: d[order], deltas),
-        n_k[order],
-        pmask[order],
-        times[order],
-    )
+    with jax.named_scope("server"):
+        times = latency_fn(_latency_key(base_key, state.round_idx), K)
+        order = jnp.argsort(jnp.where(pmask > 0, times, jnp.inf))
+        arr = (
+            jax.tree.map(lambda d: d[order], deltas),
+            n_k[order],
+            pmask[order],
+            times[order],
+        )
     v0 = state.abuf.version  # every wave client downloaded at wave start
 
     def arrival(carry, inp):
@@ -189,18 +195,20 @@ def _async_round_body(
 
         def flush(op):
             params, opt_state, flushed, t_last, stale_sum, applied = op
-            s = (buf.version - new_v).astype(jnp.float32)  # (B,) >= 0
-            disc = staleness_discount(s, beta)
-            # Discount BEFORE aggregation: the aggregator normalizes its
-            # weights, so a uniform per-flush discount folded into the
-            # weights would cancel exactly.
-            scaled = jax.tree.map(
-                lambda d: d * disc.reshape((B,) + (1,) * (d.ndim - 1)), new_deltas
-            )
-            fkey = jax.random.fold_in(akey, buf.version)
-            wbar = plane.aggregate(scaled, new_w, jnp.ones((B,), jnp.float32), fkey)
-            updates, opt_state = server_opt.update(wbar, opt_state, params)
-            params = apply_updates(params, updates)
+            with jax.named_scope("aggregate"):
+                s = (buf.version - new_v).astype(jnp.float32)  # (B,) >= 0
+                disc = staleness_discount(s, beta)
+                # Discount BEFORE aggregation: the aggregator normalizes its
+                # weights, so a uniform per-flush discount folded into the
+                # weights would cancel exactly.
+                scaled = jax.tree.map(
+                    lambda d: d * disc.reshape((B,) + (1,) * (d.ndim - 1)), new_deltas
+                )
+                fkey = jax.random.fold_in(akey, buf.version)
+                wbar = plane.aggregate(scaled, new_w, jnp.ones((B,), jnp.float32), fkey)
+            with jax.named_scope("server_step"):
+                updates, opt_state = server_opt.update(wbar, opt_state, params)
+                params = apply_updates(params, updates)
             return (params, opt_state, flushed + 1, t_i, stale_sum + s.sum(),
                     applied + B, jnp.zeros((), jnp.int32), buf.version + 1)
 
@@ -219,30 +227,32 @@ def _async_round_body(
     init = (state.params, state.opt_state, state.abuf, jnp.zeros((), jnp.int32),
             jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32),
             jnp.zeros((), jnp.int32))
-    (params, opt_state, buf, flushed, t_last, stale_sum, applied), _ = jax.lax.scan(
-        arrival, init, arr
-    )
+    with jax.named_scope("server"):
+        (params, opt_state, buf, flushed, t_last, stale_sum, applied), _ = jax.lax.scan(
+            arrival, init, arr
+        )
 
     # Wave wall-clock: the last flush's arrival time. Updates buffered
     # past the last flush are paid for by the wave that flushes them; a
     # flushless wave still observes its stream to the last participant.
-    t_stream = (times * pmask).max()
-    sim_time = jnp.where(flushed > 0, t_last, t_stream)
-    # delta_norm here is the wave's total parameter displacement (the
-    # sync engines report the aggregated pseudo-gradient norm; a wave
-    # applies 0..K server steps, so displacement is the analogue).
-    disp = jax.tree.map(lambda a, b: a - b, params, state.params)
-    n = jnp.maximum(n_k.sum(), 1.0)
-    metrics = {
-        "loss": (losses * n_k).sum() / n,
-        "examples": n_k.sum(),
-        "delta_norm": jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(disp))),
-        "corrupted": cmask.sum(),
-        **_wire_metrics(plane, state.params, pmask, K),
-        "sim_time_s": sim_time,
-        "server_steps": flushed.astype(jnp.float32),
-        "staleness_mean": stale_sum / jnp.maximum(applied.astype(jnp.float32), 1.0),
-    }
+    with jax.named_scope("server"), jax.named_scope("round_metrics"):
+        t_stream = (times * pmask).max()
+        sim_time = jnp.where(flushed > 0, t_last, t_stream)
+        # delta_norm here is the wave's total parameter displacement (the
+        # sync engines report the aggregated pseudo-gradient norm; a wave
+        # applies 0..K server steps, so displacement is the analogue).
+        disp = jax.tree.map(lambda a, b: a - b, params, state.params)
+        n = jnp.maximum(n_k.sum(), 1.0)
+        metrics = {
+            "loss": (losses * n_k).sum() / n,
+            "examples": n_k.sum(),
+            "delta_norm": jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(disp))),
+            "corrupted": cmask.sum(),
+            **_wire_metrics(plane, state.params, pmask, K),
+            "sim_time_s": sim_time,
+            "server_steps": flushed.astype(jnp.float32),
+            "staleness_mean": stale_sum / jnp.maximum(applied.astype(jnp.float32), 1.0),
+        }
     return ServerState(params, opt_state, state.round_idx + 1, ef, stale, buf), metrics
 
 

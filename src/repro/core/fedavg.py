@@ -485,15 +485,17 @@ def _sharded_code_fastpath(
     ax = sharding.axis
 
     def client_stage(p, batch, cidx, bkey, ridx):
-        return jax.vmap(
-            lambda cb, ci: _client_update(loss_fn, client_opt, sigma_fn, bkey, p, cb, ci, ridx)
-        )(batch, cidx)
+        with jax.named_scope("client"):
+            return jax.vmap(
+                lambda cb, ci: _client_update(loss_fn, client_opt, sigma_fn, bkey, p, cb, ci, ridx)
+            )(batch, cidx)
 
     if ef is None:
 
         def stage(p, batch, cidx, pm, cks, bkey, ridx):
             deltas, losses, n_k = client_stage(p, batch, cidx, bkey, ridx)
-            wbar = code_domain_aggregate(plane.compression, deltas, n_k, pm, cks, axis=ax)
+            with jax.named_scope("server"), jax.named_scope("aggregate"):
+                wbar = code_domain_aggregate(plane.compression, deltas, n_k, pm, cks, axis=ax)
             return wbar, losses, n_k
 
         wbar, losses, n_k = jax.shard_map(
@@ -507,9 +509,10 @@ def _sharded_code_fastpath(
 
     def stage_ef(p, batch, cidx, pm, cks, bkey, ridx, e):
         deltas, losses, n_k = client_stage(p, batch, cidx, bkey, ridx)
-        wbar, e2 = code_domain_aggregate_ef(
-            plane.compression, deltas, n_k, pm, cks, e, axis=ax
-        )
+        with jax.named_scope("server"), jax.named_scope("aggregate"):
+            wbar, e2 = code_domain_aggregate_ef(
+                plane.compression, deltas, n_k, pm, cks, e, axis=ax
+            )
         return wbar, losses, n_k, e2
 
     return jax.shard_map(
@@ -564,13 +567,23 @@ def _fedavg_round_body(
     sharding: Optional[ClientSharding] = None,
 ):
     """One FedAvg round: client deltas -> cohort -> compression ->
-    corruption -> aggregator -> server optimizer (one jitted graph)."""
+    corruption -> aggregator -> server optimizer (one jitted graph).
+
+    The stages run under ``jax.named_scope``s — ``client``, and
+    ``server`` with its children ``cohort``, ``uplink``, ``aggregate``,
+    ``server_step`` and ``round_metrics`` — that every compiled
+    instruction carries in its ``op_name``, so a device trace splits the
+    round's time by stage. XLA fuses across them (the aggregate's mean
+    lands in the server step's fusion), so only ``client`` against
+    ``server`` is a hard boundary."""
     plane = plane or _PARITY_PLANE
     K = jax.tree.leaves(round_batch)[0].shape[0]
     ckey, qkey, akey, xkey = _plane_keys(base_key, state.round_idx)
 
-    round_batch, pmask = _apply_cohort(plane, ckey, round_batch)
-    ckeys = _client_key_fanout(plane, qkey, K)
+    with jax.named_scope("server"), jax.named_scope("cohort"):
+        round_batch, pmask = _apply_cohort(plane, ckey, round_batch)
+    with jax.named_scope("server"), jax.named_scope("uplink"):
+        ckeys = _client_key_fanout(plane, qkey, K)
 
     ef = state.ef
     if _code_fast_path(plane) and sharding is not None:
@@ -596,39 +609,48 @@ def _fedavg_round_body(
         # identity (delta adversaries force the slow path), matching
         # its cmask = 0. EF planes route through the _ef twin, whose
         # residual update reads the transmitted codes directly.
-        deltas, losses, n_k = _client_update_stage(
-            loss_fn, client_opt, sigma_fn, base_key, state.params, round_batch,
-            state.round_idx,
-        )
-        if plane.compression.error_feedback:
-            wbar, ef = code_domain_aggregate_ef(
-                plane.compression, deltas, n_k, pmask, ckeys, ef
+        with jax.named_scope("client"):
+            deltas, losses, n_k = _client_update_stage(
+                loss_fn, client_opt, sigma_fn, base_key, state.params, round_batch,
+                state.round_idx,
             )
-        else:
-            wbar = code_domain_aggregate(plane.compression, deltas, n_k, pmask, ckeys)
+        with jax.named_scope("server"), jax.named_scope("aggregate"):
+            if plane.compression.error_feedback:
+                wbar, ef = code_domain_aggregate_ef(
+                    plane.compression, deltas, n_k, pmask, ckeys, ef
+                )
+            else:
+                wbar = code_domain_aggregate(plane.compression, deltas, n_k, pmask, ckeys)
         cmask = jnp.zeros((K,), jnp.float32)
         stale = state.stale
     else:
-        deltas, losses, n_k = _client_update_stage(
-            loss_fn, client_opt, sigma_fn, base_key, state.params, round_batch,
-            state.round_idx, sharding,
-        )
-        deltas, ef, cmask, stale = _delta_payload_stage(
-            plane, deltas, ef, pmask, ckeys, xkey, state.stale
-        )
-        wbar = plane.aggregate(deltas, n_k, pmask, akey)
+        with jax.named_scope("client"):
+            deltas, losses, n_k = _client_update_stage(
+                loss_fn, client_opt, sigma_fn, base_key, state.params, round_batch,
+                state.round_idx, sharding,
+            )
+        with jax.named_scope("server"):
+            with jax.named_scope("uplink"):
+                deltas, ef, cmask, stale = _delta_payload_stage(
+                    plane, deltas, ef, pmask, ckeys, xkey, state.stale
+                )
+            with jax.named_scope("aggregate"):
+                wbar = plane.aggregate(deltas, n_k, pmask, akey)
 
-    updates, opt_state = server_opt.update(wbar, state.opt_state, state.params)
-    params = apply_updates(state.params, updates)
-    n = jnp.maximum(n_k.sum(), 1.0)
-    metrics = {
-        "loss": (losses * n_k).sum() / n,
-        "examples": n_k.sum(),
-        "delta_norm": jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(wbar))),
-        "corrupted": cmask.sum(),
-        **_wire_metrics(plane, state.params, pmask, K),
-        **_sim_time_metrics(latency_fn, base_key, state.round_idx, pmask, K),
-    }
+    with jax.named_scope("server"):
+        with jax.named_scope("server_step"):
+            updates, opt_state = server_opt.update(wbar, state.opt_state, state.params)
+            params = apply_updates(state.params, updates)
+        with jax.named_scope("round_metrics"):
+            n = jnp.maximum(n_k.sum(), 1.0)
+            metrics = {
+                "loss": (losses * n_k).sum() / n,
+                "examples": n_k.sum(),
+                "delta_norm": jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(wbar))),
+                "corrupted": cmask.sum(),
+                **_wire_metrics(plane, state.params, pmask, K),
+                **_sim_time_metrics(latency_fn, base_key, state.round_idx, pmask, K),
+            }
     return ServerState(params, opt_state, state.round_idx + 1, ef, stale, state.abuf), metrics
 
 
@@ -734,37 +756,44 @@ def _fedsgd_round_body(
     plane = plane or _PARITY_PLANE
     K, S = jax.tree.leaves(round_batch)[0].shape[:2]
     ckey, qkey, _, _ = _plane_keys(base_key, state.round_idx)
-    round_batch, pmask = _apply_cohort(plane, ckey, round_batch)
-    flat = jax.tree.map(lambda x: x.reshape((K * S * x.shape[2],) + x.shape[3:]), round_batch)
-    key = fvn_lib.fvn_key(base_key, state.round_idx, 0, 0)
-    p_eval = (
-        state.params
-        if sigma_fn is None
-        else fvn_lib.perturb(state.params, key, sigma_fn(state.round_idx))
-    )
-    data_key = jax.random.fold_in(key, 1)
-    (loss, _aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(p_eval, flat, data_key)
-    # delta of the 1-step client update = client_lr * grad
-    wbar = jax.tree.map(lambda g: client_lr * g.astype(jnp.float32), grads)
-    if plane.compression.kind != "none":
-        # the collapsed engine has no per-client deltas; quantizing the
-        # aggregate is the server-side proxy (bytes still counted
-        # per reporting client in the wire metrics)
-        wbar = plane.compress(wbar, qkey)
-    updates, opt_state = server_opt.update(wbar, state.opt_state, state.params)
-    params = apply_updates(state.params, updates)
-    w = flat.get("weight")
-    n = w.sum() if w is not None else jnp.asarray(K * S, jnp.float32)
-    metrics = {
-        "loss": loss,
-        "examples": n,
-        "delta_norm": jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(wbar))),
-        # delta corruptions are fedavg-only (no per-client deltas here);
-        # the data-plane label_shuffle adversary reports host-side
-        "corrupted": jnp.float32(0.0),
-        **_wire_metrics(plane, state.params, pmask, K),
-        **_sim_time_metrics(latency_fn, base_key, state.round_idx, pmask, K),
-    }
+    with jax.named_scope("server"), jax.named_scope("cohort"):
+        round_batch, pmask = _apply_cohort(plane, ckey, round_batch)
+    with jax.named_scope("client"):
+        flat = jax.tree.map(lambda x: x.reshape((K * S * x.shape[2],) + x.shape[3:]), round_batch)
+        key = fvn_lib.fvn_key(base_key, state.round_idx, 0, 0)
+        p_eval = (
+            state.params
+            if sigma_fn is None
+            else fvn_lib.perturb(state.params, key, sigma_fn(state.round_idx))
+        )
+        data_key = jax.random.fold_in(key, 1)
+        (loss, _aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(p_eval, flat, data_key)
+    with jax.named_scope("server"):
+        with jax.named_scope("aggregate"):
+            # delta of the 1-step client update = client_lr * grad
+            wbar = jax.tree.map(lambda g: client_lr * g.astype(jnp.float32), grads)
+        if plane.compression.kind != "none":
+            # the collapsed engine has no per-client deltas; quantizing the
+            # aggregate is the server-side proxy (bytes still counted
+            # per reporting client in the wire metrics)
+            with jax.named_scope("uplink"):
+                wbar = plane.compress(wbar, qkey)
+        with jax.named_scope("server_step"):
+            updates, opt_state = server_opt.update(wbar, state.opt_state, state.params)
+            params = apply_updates(state.params, updates)
+        with jax.named_scope("round_metrics"):
+            w = flat.get("weight")
+            n = w.sum() if w is not None else jnp.asarray(K * S, jnp.float32)
+            metrics = {
+                "loss": loss,
+                "examples": n,
+                "delta_norm": jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree.leaves(wbar))),
+                # delta corruptions are fedavg-only (no per-client deltas here);
+                # the data-plane label_shuffle adversary reports host-side
+                "corrupted": jnp.float32(0.0),
+                **_wire_metrics(plane, state.params, pmask, K),
+                **_sim_time_metrics(latency_fn, base_key, state.round_idx, pmask, K),
+            }
     return ServerState(
         params, opt_state, state.round_idx + 1, state.ef, state.stale, state.abuf
     ), metrics

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.data.strategies import get_strategy
 from repro.data.synthetic import label_shuffle
+from repro.profile.trace import span
 
 
 @dataclasses.dataclass
@@ -43,9 +44,10 @@ class RoundBatch:
 
     def engine_batch(self) -> dict:
         """The round engine's input layout (mask enters as "weight")."""
-        return {"features": self.features, "labels": self.labels,
-                "frame_len": self.frame_len, "label_len": self.label_len,
-                "weight": self.mask}
+        with span("sampler.engine_batch"):
+            return {"features": self.features, "labels": self.labels,
+                    "frame_len": self.frame_len, "label_len": self.label_len,
+                    "weight": self.mask}
 
     def pad_steps(self, steps: int) -> "RoundBatch":
         """Append weight-0 local steps up to ``steps`` (exact no-ops
@@ -230,10 +232,11 @@ class FederatedSampler:
         return rb
 
     def next_round(self) -> RoundBatch:
-        rb = self._next_round()
-        if self.label_shuffle_rate > 0.0:
-            rb = self._shuffle_labels(rb)
-        return rb
+        with span("sampler.next_round"):
+            rb = self._next_round()
+            if self.label_shuffle_rate > 0.0:
+                rb = self._shuffle_labels(rb)
+            return rb
 
     def _next_round(self) -> RoundBatch:
         K, b, S = self.K, self.b, self.steps

@@ -9,12 +9,20 @@ the two with a background thread and a small bounded buffer
 
 One worker thread keeps the sampler's RNG stream strictly ordered, so
 prefetched runs are bit-identical to serial runs.
+
+Host spans (``repro.profile.trace.span``): ``repro.prefetch.put``
+around the worker's host-to-device copy, ``repro.prefetch.wait``
+around the consumer's blocking wait for the next item.
 """
 from __future__ import annotations
 
 import queue
 import threading
 from typing import Any, Callable, Iterable, Iterator, Optional
+
+import jax
+
+from repro.profile.trace import span
 
 _END = object()
 
@@ -73,12 +81,11 @@ class PrefetchIterator:
                 if self._transform is not None:
                     item = self._transform(item)
                 if self._device_put:
-                    import jax
-
-                    if self._sharding is not None:
-                        item = jax.device_put(item, self._sharding)
-                    else:
-                        item = jax.device_put(item)
+                    with span("prefetch.put"):
+                        if self._sharding is not None:
+                            item = jax.device_put(item, self._sharding)
+                        else:
+                            item = jax.device_put(item)
                 if not self._put(item):
                     return
         except BaseException as e:  # surfaced on the consumer thread
@@ -92,6 +99,10 @@ class PrefetchIterator:
     def __next__(self):
         if self._done:
             raise StopIteration
+        with span("prefetch.wait"):
+            return self._next()
+
+    def _next(self):
         while True:
             try:
                 item = self._queue.get(timeout=0.5)

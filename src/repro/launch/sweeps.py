@@ -322,12 +322,13 @@ class SweepRunner:
                    else map(lambda b: jax.tree.map(jax.numpy.asarray, b),
                             host_batches()))
         try:
-            for batch in batches:
+            for r, batch in enumerate(batches):
                 # the float() pulls synchronize, so the section times
                 # dispatch + device compute (round 1 includes compile;
-                # min_s is the steady-state round — what calibration
-                # consumes)
-                with rec.section("round"):
+                # min_s is the steady-state round); the step annotation
+                # marks the round in a profiler trace
+                with jax.profiler.StepTraceAnnotation("round", step_num=r), \
+                        rec.section("round"):
                     state, metrics = round_fn(state, batch, hypers, base_key)
                     losses.append(float(metrics["loss"]))
                 participants.append(float(metrics["participants"]))
@@ -499,12 +500,13 @@ class SweepRunner:
                    if self.prefetch
                    else map(lambda b: jax.device_put(b, shard), host_batches()))
         try:
-            for batch in batches:
-                state, metrics = round_fn(state, batch, hypers, keys)
-                for k, per_point in series.items():
-                    vals = np.asarray(metrics[k])
-                    for i in range(m):
-                        per_point[i].append(float(vals[i]))
+            for r, batch in enumerate(batches):
+                with jax.profiler.StepTraceAnnotation("round", step_num=r):
+                    state, metrics = round_fn(state, batch, hypers, keys)
+                    for k, per_point in series.items():
+                        vals = np.asarray(metrics[k])
+                        for i in range(m):
+                            per_point[i].append(float(vals[i]))
         finally:
             if self.prefetch:
                 batches.close()

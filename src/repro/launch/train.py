@@ -220,8 +220,9 @@ def run_federated(
     try:
         for r, batch in enumerate(batches):
             # float() blocks, so the section covers dispatch + device
-            # compute; round 1 includes compile — min_s is steady-state
-            with rec.section("round"):
+            # compute; round 1 includes compile — min_s is steady-state.
+            # The step annotation marks the round in a profiler trace.
+            with jax.profiler.StepTraceAnnotation("round", step_num=r), rec.section("round"):
                 state, metrics = round_step(state, batch)
                 losses.append(float(metrics["loss"]))
             participants.append(float(metrics["participants"]))

@@ -117,8 +117,10 @@ def joint_logprobs_ref(cfg: RNNTConfig, params, enc, pred, labels, u_chunk: int 
         return None, (blank, lab)
 
     _, (blanks, labs) = jax.lax.scan(jax.checkpoint(body), None, (gc, lc))
-    blank_lp = blanks.swapaxes(0, 1).reshape(B, T, -1)[:, :, :U1]
-    label_lp = labs.swapaxes(0, 1).reshape(B, T, -1)[:, :, :U1]
+    # (n_chunks, B, T, c) -> (B, T, n_chunks*c): the chunk axis lands
+    # just outside the within-chunk axis before flattening
+    blank_lp = jnp.moveaxis(blanks, 0, 2).reshape(B, T, -1)[:, :, :U1]
+    label_lp = jnp.moveaxis(labs, 0, 2).reshape(B, T, -1)[:, :, :U1]
     return blank_lp, label_lp
 
 
@@ -134,30 +136,40 @@ def joint_logits(cfg: RNNTConfig, params, enc_t, pred_u):
 
 def loss_fn(cfg: RNNTConfig, params, batch, rng=None):
     """batch: features (B,T,F), labels (B,U), frame_len (B,), label_len (B,),
-    optional weight (B,). Returns (mean loss, aux)."""
+    optional weight (B,). Returns (mean loss, aux).
+
+    Each layer runs under its own ``jax.named_scope`` (``specaugment``,
+    ``encoder``, ``prednet``, ``joint``, ``rnnt_loss``): the names reach
+    every compiled instruction's ``op_name``, so a device trace splits
+    the step's time by layer."""
     feats = batch["features"]
     if rng is not None and cfg.specaug.enabled:
-        feats = spec_augment(rng, feats, cfg.specaug)
-    enc = encode(cfg, params, feats)
-    pred = predict(cfg, params, batch["labels"])
-    if cfg.use_kernel:
-        from repro.kernels.ops import rnnt_joint
-        e = enc @ params["joint_enc"].astype(enc.dtype)
-        g = pred @ params["joint_pred"].astype(pred.dtype)
-        lbl = jnp.concatenate(
-            [batch["labels"], jnp.zeros((batch["labels"].shape[0], 1), batch["labels"].dtype)],
-            axis=1)
-        blank_lp, label_lp = rnnt_joint(
-            e, g, params["joint_out"], params["joint_bias"], lbl)
-    else:
-        blank_lp, label_lp = joint_logprobs_ref(cfg, params, enc, pred, batch["labels"])
-    frame_len = jnp.maximum(batch["frame_len"] // cfg.time_stride, 1)
-    nll = rnnt_loss_from_logprobs(blank_lp, label_lp, frame_len, batch["label_len"])
-    if cfg.loss_norm:
-        nll = nll / jnp.maximum(batch["label_len"].astype(jnp.float32), 1.0)
-    w = batch.get("weight", jnp.ones_like(nll))
-    denom = jnp.maximum(w.sum(), 1.0)
-    loss = (nll * w).sum() / denom
+        with jax.named_scope("specaugment"):
+            feats = spec_augment(rng, feats, cfg.specaug)
+    with jax.named_scope("encoder"):
+        enc = encode(cfg, params, feats)
+    with jax.named_scope("prednet"):
+        pred = predict(cfg, params, batch["labels"])
+    with jax.named_scope("joint"):
+        if cfg.use_kernel:
+            from repro.kernels.ops import rnnt_joint
+            e = enc @ params["joint_enc"].astype(enc.dtype)
+            g = pred @ params["joint_pred"].astype(pred.dtype)
+            lbl = jnp.concatenate(
+                [batch["labels"], jnp.zeros((batch["labels"].shape[0], 1), batch["labels"].dtype)],
+                axis=1)
+            blank_lp, label_lp = rnnt_joint(
+                e, g, params["joint_out"], params["joint_bias"], lbl)
+        else:
+            blank_lp, label_lp = joint_logprobs_ref(cfg, params, enc, pred, batch["labels"])
+    with jax.named_scope("rnnt_loss"):
+        frame_len = jnp.maximum(batch["frame_len"] // cfg.time_stride, 1)
+        nll = rnnt_loss_from_logprobs(blank_lp, label_lp, frame_len, batch["label_len"])
+        if cfg.loss_norm:
+            nll = nll / jnp.maximum(batch["label_len"].astype(jnp.float32), 1.0)
+        w = batch.get("weight", jnp.ones_like(nll))
+        denom = jnp.maximum(w.sum(), 1.0)
+        loss = (nll * w).sum() / denom
     return loss, {"nll": nll}
 
 

@@ -1,5 +1,5 @@
 """Fed-round cost predictor: static FLOP/byte counts x per-device
-coefficients calibrated from captured traces.
+coefficients calibrated from measured rounds.
 
 Prices any ``FederatedPlan`` WITHOUT running it, on two axes:
 
@@ -12,7 +12,7 @@ Prices any ``FederatedPlan`` WITHOUT running it, on two axes:
 - ``seconds`` — wall time needs the device. A round's static cost
   features (FLOPs, HBM bytes, wire bytes, server steps) map to seconds
   through per-device coefficients fit by non-negative least squares
-  over measured traces (:func:`calibrate`), the byteprofile replayer
+  over measured rounds (:func:`calibrate`), the byteprofile replayer
   idea with the repo's own HLO cost model as the DAG side. Two feature
   sources share one coefficient shape: ``hlo`` (exact counts from
   ``launch/hlo_cost`` over the compiled round step — used when a

@@ -1,12 +1,12 @@
 """Trace capture: versioned trace JSON from real runs, one writer for
-train / sweeps / bench.
+train / sweeps / bench, and the program's host spans on the profiler's
+clock.
 
-The profiling plane's ground truth. Every emitter — the train driver's
-round loop, the sweep runner's per-point timing, the kernel micro
-benches, the roofline predictor — produces the SAME record shape
-through :func:`write_trace` (mirroring how ``repro.core.metrics`` owns
-one summary-row schema for train/sweeps/bench), so the predictor's
-calibration can consume any of them:
+Every emitter — the train driver's round loop, the sweep runner's
+per-point timing, the kernel micro benches, the roofline predictor's
+report — produces the SAME record shape through :func:`write_trace`
+(mirroring how ``repro.core.metrics`` owns one summary-row schema for
+train/sweeps/bench):
 
 - ``schema_version`` / ``kind``: one of :data:`TRACE_KINDS`;
 - ``device`` + ``device_key``: the fingerprint that keys tuning.json —
@@ -16,16 +16,25 @@ calibration can consume any of them:
   against compiled-graph identities, not point names;
 - ``sections``: per-stage wall timers ({count, total_s, min_s,
   mean_s}) from a :class:`TraceRecorder` wrapped around the host
-  pipeline stages (pack -> round step -> eval; the round step itself
-  is ONE jitted graph, so in-graph stages are priced by the HLO cost
-  model instead);
+  pipeline stages (pack -> round step -> eval);
 - ``kernels``: per-kernel us from the micro benches;
 - ``features`` + ``counters``: the predictor's static per-round cost
   features and run bookkeeping (rounds, n_params, ...).
 
+The records are for reading; the predictor calibrates from its own
+interleaved measurements (``predict.predict_report``), not from them.
+
+Host spans: :func:`span` is a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>``, and every :class:`TraceRecorder` section opens one,
+so a device trace can put an idle gap down to what the host was doing
+(``repro.pack``, ``repro.round``, ``repro.prefetch.put`` ...). Inside
+the jitted round step the stages are ``jax.named_scope``s instead
+(``core/fedavg.py``, ``models/rnnt.py``): compile-time names on each
+device operation.
+
 Also home to :func:`measure_interleaved_min` — the order-rotating
-min-of-reps protocol the fed_round bench established (PR 5/6), shared
-here so benches and the predictor measure the same way.
+min-of-reps protocol of the fed_round bench, shared here so benches and
+the predictor measure the same way.
 """
 
 from __future__ import annotations
@@ -75,10 +84,20 @@ def device_key(fp: Optional[dict] = None) -> str:
 
 # ----------------------------------------------------------- recorder
 
+SPAN_PREFIX = "repro."
+
+
+def span(name: str):
+    """A host span ``repro.<name>`` on the profiler's clock: an
+    annotation only, which costs a few microseconds when no trace is
+    being taken and keeps no record of its own."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
+
 
 class TraceRecorder:
     """Lightweight per-section wall timers (thread-safe: the data
-    plane's prefetch worker packs on a background thread)."""
+    plane's prefetch worker packs on a background thread). Each section
+    is also a :func:`span` of the same name."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -90,20 +109,12 @@ class TraceRecorder:
 
     @contextmanager
     def section(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
-
-    def wrap(self, name: str, fn: Callable) -> Callable:
-        """fn -> fn that times every call into section ``name``."""
-
-        def timed(*args, **kwargs):
-            with self.section(name):
-                return fn(*args, **kwargs)
-
-        return timed
+        with span(name):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.add(name, time.perf_counter() - t0)
 
     def stats(self) -> dict:
         with self._lock:
@@ -202,25 +213,6 @@ def write_trace(path: str, kind: str, **kwargs) -> str:
 def load_trace(path: str) -> dict:
     with open(path) as f:
         return validate_trace(json.load(f))
-
-
-def load_traces(dirpath: str, kind: Optional[str] = None) -> list[dict]:
-    """All ``trace_*.json`` records under ``dirpath`` (optionally one
-    kind), skipping files that fail validation — foreign/stale traces
-    must not break calibration."""
-    out = []
-    if not os.path.isdir(dirpath):
-        return out
-    for name in sorted(os.listdir(dirpath)):
-        if not (name.startswith("trace_") and name.endswith(".json")):
-            continue
-        try:
-            rec = load_trace(os.path.join(dirpath, name))
-        except (ValueError, json.JSONDecodeError, OSError):
-            continue
-        if kind is None or rec["kind"] == kind:
-            out.append(rec)
-    return out
 
 
 # -------------------------------------------------------- measurement

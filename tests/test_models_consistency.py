@@ -3,6 +3,7 @@ MLA absorbed decode == expanded forward, SSM/RWKV state streaming."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.models import build_model
 from repro.models.hybrid import HybridConfig
@@ -187,3 +188,30 @@ def test_hybrid_chunked_flag():
     h1 = forward(cfg, p, tok)
     h2 = forward(dc.replace(cfg, ssm_chunked=True), p, tok)
     np.testing.assert_allclose(np.asarray(h2), np.asarray(h1), atol=3e-5)
+
+
+@pytest.mark.parametrize("U1", [13, 15, 16, 17, 37, 61])
+def test_rnnt_joint_ref_matches_dense(U1):
+    """The training joint scans U+1 in chunks of 8 and puts them back in
+    lattice order: from U+1 = 16 on there is more than one chunk, and a
+    wrong reassembly interleaves the chunk and frame axes."""
+    from repro.models.rnnt import RNNTConfig, init_params, joint_logprobs_ref
+
+    cfg = RNNTConfig(vocab=48, enc_hidden=24, pred_hidden=20, joint_dim=16, enc_layers=1,
+                     pred_layers=1, pred_embed=8, feat_dim=8)
+    params = init_params(cfg, jax.random.PRNGKey(U1))
+    B, T = 2, 11
+    ke, kp, kl = jax.random.split(jax.random.PRNGKey(7), 3)
+    enc = jax.random.normal(ke, (B, T, cfg.enc_hidden))
+    pred = jax.random.normal(kp, (B, U1, cfg.pred_hidden))
+    labels = jax.random.randint(kl, (B, U1 - 1), 1, cfg.vocab)
+    blank, label = joint_logprobs_ref(cfg, params, enc, pred, labels)
+
+    h = jnp.tanh((enc @ params["joint_enc"])[:, :, None, :]
+                 + (pred @ params["joint_pred"])[:, None, :, :])
+    logp = jax.nn.log_softmax(h @ params["joint_out"] + params["joint_bias"], axis=-1)
+    nxt = jnp.concatenate([labels, jnp.zeros((B, 1), labels.dtype)], axis=1)
+    want_label = jnp.take_along_axis(logp, nxt[:, None, :, None], axis=-1)[..., 0]
+    assert blank.shape == label.shape == (B, T, U1)
+    np.testing.assert_allclose(np.asarray(blank), np.asarray(logp[..., 0]), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(label), np.asarray(want_label), atol=1e-5)

@@ -1,0 +1,55 @@
+"""The program names the round's stages and the RNN-T's layers on its
+compiled instructions: the tiny RNN-T round step, compiled on the CPU,
+has at least one instruction in every stage of the benchmark's table
+(chipbench/scopes.py), on the fedavg fp32 path, the int8 code-domain
+fast path and the fedsgd engine."""
+import sys
+from pathlib import Path
+
+import jax
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from chipbench import scopes  # noqa: E402
+from repro.core import CompressionConfig, FederatedPlan, build_round_engine, get_task  # noqa: E402
+from repro.data import FederatedSampler  # noqa: E402
+
+PLANS = {
+    "fedavg-fp32": {},
+    "fedavg-int8": {"compression": CompressionConfig(kind="int8")},
+    "fedsgd": {"engine": "fedsgd"},
+}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    task = get_task("asr-rnnt")
+    return task, task.make_corpus(0), task.bundle.init(jax.random.PRNGKey(0))
+
+
+def _compiled_text(tiny, plan_kw) -> str:
+    task, corpus, params = tiny
+    plan = FederatedPlan(clients_per_round=2, local_batch_size=2, data_limit=4, **plan_kw)
+    engine = build_round_engine(plan, task, base_key=jax.random.PRNGKey(1))
+    batch = FederatedSampler(corpus, 2, 2, data_limit=4, seed=0).next_round().engine_batch()
+    if plan.engine == "fedsgd":  # one local step a round
+        batch = {k: v[:, :1] for k, v in batch.items()}
+    state = engine.init_state(params)
+    return jax.jit(engine.step).lower(state, batch).compile().as_text()
+
+
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_every_stage_has_instructions(tiny, plan):
+    names = scopes.op_scopes(_compiled_text(tiny, PLANS[plan]))
+    assert names
+    count = {}
+    for op_name in names.values():
+        s = scopes.stage(op_name)
+        count[s] = count.get(s, 0) + 1
+    missing = [s for s in scopes.STAGES if not count.get(s)]
+    assert not missing, f"stages with no instruction: {missing} ({count})"
+    # what no scope names is a small remainder (reduction bodies, XLA's own)
+    assert count.get(scopes.UNSCOPED, 0) < len(names) / 10
