@@ -1,14 +1,19 @@
 """LSTM stack (the paper's RNN-T encoder substrate).
 
-Gates are computed as one fused (in+hidden) x 4h matmul per step; the
-elementwise gate nonlinearities + state update are the Pallas
-``lstm_gates`` kernel's target (ref path inline here). Sequence
-iteration is ``lax.scan``; multi-layer stacks scan over a stacked
-parameter axis when dims are homogeneous, else loop per layer.
+The input matmul is hoisted out of the time loop (one (B*S) x d_in x 4h
+matmul); each step adds h @ w_hh and applies the gate nonlinearities +
+state update, the Pallas ``lstm_gates`` kernel's target (ref path
+inline here). Sequence iteration is ``lax.scan`` under a custom VJP
+whose backward carries only (dh, dc) through the reversed time loop and
+forms the recurrent weight's gradient after it, as one matmul over all
+S*B rows: ``dW_hh = sum_t h_{t-1}^T dgates_t``. On TPU, eligible shapes
+instead run the full-scan Pallas kernel, which keeps w_hh and its
+gradient VMEM-resident. Multi-layer stacks loop per layer.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -96,18 +101,71 @@ def _scan_kernel_eligible(S: int, d_h: int, chunk: int) -> bool:
     return mode == "pallas" or jax.default_backend() != "cpu"
 
 
+def _lstm_step(w_hh, carry, xg_t):
+    h, c = carry
+    gates = xg_t + h @ w_hh.astype(xg_t.dtype)
+    h, c = _lstm_gates_dispatch(gates, c)
+    return (h, c), h
+
+
+def _lstm_scan(w_hh, xg, h0, c0, unroll):
+    """xg: (S, B, 4h) time-major input pre-activations -> ys (S, B, h),
+    final (h, c). ``h0`` None is the zero initial state."""
+    if h0 is None:
+        h0 = jnp.zeros(xg.shape[1:-1] + w_hh.shape[:1], xg.dtype)
+    (h, c), ys = jax.lax.scan(functools.partial(_lstm_step, w_hh), (h0, c0), xg, unroll=unroll)
+    return ys, h, c
+
+
+_lstm_recurrence = jax.custom_vjp(_lstm_scan, nondiff_argnums=(4,))
+
+
+def _lstm_recurrence_fwd(w_hh, xg, h0, c0, unroll):
+    # w_hh is closed over, not differentiated: the transposed scan then
+    # carries only (dh, dc) and reads w_hh as a constant.
+    out, vjp = jax.vjp(lambda xg, h0, c0: _lstm_scan(w_hh, xg, h0, c0, unroll), xg, h0, c0)
+    return out, (w_hh, h0, out[0], vjp)
+
+
+def _lstm_recurrence_bwd(unroll, res, cts):
+    w_hh, h0, ys, vjp = res
+    dxg, dh0, dc0 = vjp(cts)
+    # dgates_t is dxg_t; h_{t-1} is ys[t-1], h0 at t = 0. Separate dots,
+    # so no shifted copy of ys is made; every product summed in float32.
+    # A zero initial state adds nothing: its dot would still write a
+    # float32 (H, 4H) result for the main dot to read back.
+    with jax.named_scope("w_hh_grad"):
+        dot = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+        dw = dot("sbh,sbg->hg", ys[:-1], dxg[1:])
+        if h0 is not None:
+            dw = dw + dot("bh,bg->hg", h0, dxg[0])
+    return dw.astype(w_hh.dtype), dxg, dh0, dc0
+
+
+_lstm_recurrence.defvjp(_lstm_recurrence_fwd, _lstm_recurrence_bwd)
+
+
 def lstm_layer(p, xs, h0=None, c0=None, unroll: int = 1, chunk: int = 0):
     """xs: (B, S, d_in) -> (B, S, d_hidden), (h, c) final.
 
     ``unroll`` replicates the step body inside each while iteration so
     the recurrent weight matrix is fetched once per ``unroll`` steps
-    (the §Perf weight-amortization lever). On TPU, eligible shapes
-    dispatch the full-scan Pallas kernel instead (``lstm_scan_fused``):
-    the whole sequence runs in ONE pallas_call whose w_hh block is
-    fetched once and stays VMEM-resident for all S steps, with a fused
-    reversed-scan custom-VJP backward that recomputes the gate
-    preactivations in VMEM (thresholds in the tuning registry;
-    `--autotune lstm` re-measures them)."""
+    (the §Perf weight-amortization lever). The ``lax.scan`` path's
+    backward never threads a w_hh gradient through the time loop: the
+    reversed scan emits dgates_t (the gradient of the hoisted input
+    pre-activations) and reads w_hh only for dh_{t-1} = dgates_t w_hh^T;
+    ``dW_hh`` is then one float32-accumulated matmul contracted over
+    (S, B), plus ``h0``'s term where an initial state is given, under
+    the ``w_hh_grad`` scope. ``chunk`` runs the
+    gradient-checkpointed scan under plain autodiff instead.
+
+    On TPU, eligible shapes dispatch the full-scan Pallas kernel instead
+    (``lstm_scan_fused``): the whole sequence runs in ONE pallas_call
+    whose w_hh block is fetched once and stays VMEM-resident for all S
+    steps, with a fused reversed-scan custom-VJP backward that
+    recomputes the gate preactivations in VMEM and keeps dW_hh in VMEM
+    (thresholds in the tuning registry; `--autotune lstm` re-measures
+    them)."""
     B, S, _ = xs.shape
     d_h = p["w_hh"].shape[0]
     h = jnp.zeros((B, d_h), xs.dtype) if h0 is None else h0
@@ -125,18 +183,13 @@ def lstm_layer(p, xs, h0=None, c0=None, unroll: int = 1, chunk: int = 0):
         )
         return ys.swapaxes(0, 1), (hT.astype(xs.dtype), cT)
 
-    def step(carry, xg_t):
-        h, c = carry
-        gates = xg_t + h @ p["w_hh"].astype(xg_t.dtype)
-        h, c = _lstm_gates_dispatch(gates, c)
-        return (h, c), h
-
     if chunk:
         from repro.models.layers import chunked_scan
 
+        step = functools.partial(_lstm_step, p["w_hh"])
         (h, c), ys = chunked_scan(step, (h, c), xg.swapaxes(0, 1), chunk=chunk, unroll=unroll)
     else:
-        (h, c), ys = jax.lax.scan(step, (h, c), xg.swapaxes(0, 1), unroll=unroll)
+        ys, h, c = _lstm_recurrence(p["w_hh"], xg.swapaxes(0, 1), h0, c, unroll)
     return ys.swapaxes(0, 1), (h, c)
 
 

@@ -55,9 +55,11 @@ def test_tiny_and_arch_presets_keep_the_default_corpus():
 
 
 # Losses of `train --rounds 3 --clients 4 --batch 2 --data-limit 4` on
-# the CPU, recorded before --preset full existed: both spellings of the
+# the CPU, recorded before --preset full existed and again when the LSTM
+# backward began to sum dW_hh in one matmul after its time loop (the
+# third round's loss moved in its seventh digit): both spellings of the
 # container-scale run must reproduce them exactly.
-TINY_LOSSES = [9.611196517944336, 9.723388671875, 7.5151567459106445]
+TINY_LOSSES = [9.611196517944336, 9.723388671875, 7.515156269073486]
 
 
 @pytest.mark.parametrize("argv", [["--preset", "tiny"], ["--task", "asr-rnnt"]])

@@ -53,3 +53,12 @@ def test_every_stage_has_instructions(tiny, plan):
     assert not missing, f"stages with no instruction: {missing} ({count})"
     # what no scope names is a small remainder (reduction bodies, XLA's own)
     assert count.get(scopes.UNSCOPED, 0) < len(names) / 10
+
+
+def test_w_hh_grad_booked_to_its_layer(tiny):
+    """The LSTM backward forms dW_hh after its time loop under the
+    ``w_hh_grad`` scope; those instructions exist in the round step and
+    count to the encoder's backward and to the prediction network."""
+    names = scopes.op_scopes(_compiled_text(tiny, PLANS["fedavg-fp32"]))
+    booked = {scopes.stage(n) for n in names.values() if "/w_hh_grad/" in n}
+    assert booked == {"encoder_bwd", "prednet"}
