@@ -91,9 +91,15 @@ def lm_train_batch(shape: InputShape, K: int, S: int, b: int, dtype="bfloat16"):
     }
 
 
+def audio_frames(cfg) -> tuple:
+    """(frames, width) of one utterance: log-mel frames at twice the
+    encoder's positions, or (with ``n_mels == 0``) frame embeddings."""
+    return (2 * cfg.max_source, cfg.n_mels) if cfg.n_mels else (cfg.max_source, cfg.d_model)
+
+
 def audio_train_batch(shape: InputShape, K: int, S: int, b: int, cfg):
     return {
-        "frames": sds((K, S, b, cfg.max_source, cfg.d_model), cfg.dtype),
+        "frames": sds((K, S, b, *audio_frames(cfg)), cfg.dtype),
         "tokens": sds((K, S, b, shape.seq_len), "int32"),
         "weight": sds((K, S, b), "float32"),
     }
@@ -128,7 +134,7 @@ def lm_prefill_batch(shape: InputShape):
 
 def audio_prefill_batch(shape: InputShape, cfg):
     return {
-        "frames": sds((shape.global_batch, cfg.max_source, cfg.d_model), cfg.dtype),
+        "frames": sds((shape.global_batch, *audio_frames(cfg)), cfg.dtype),
         "tokens": sds((shape.global_batch, shape.seq_len), "int32"),
     }
 

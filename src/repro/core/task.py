@@ -119,8 +119,9 @@ def _lm_adapt(batch: dict) -> dict:
 
 
 def _encdec_adapt(batch: dict) -> dict:
-    """Enc-dec (Whisper-style) consumes precomputed frame embeddings;
-    the corpus feature dim doubles as d_model at container scale."""
+    """Enc-dec (Whisper-style): the corpus features are the encoder's
+    frames, log-mel bins (``n_mels`` wide) or, with ``n_mels == 0``,
+    frame embeddings (``d_model`` wide)."""
     return {
         "frames": batch["features"],
         "tokens": batch["labels"],

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_arch, list_archs
+from repro.configs import base, get_arch, list_archs
 from repro.core import FederatedPlan, init_server_state, make_round_step
 from repro.models import build_model
 
@@ -19,7 +19,7 @@ def smoke_batch(arch, cfg, K=2, S=1, b=2, seq=32):
     w = np.ones((K, S, b), np.float32)
     if kind == "audio":
         return {
-            "frames": jnp.asarray(RNG.normal(size=(K, S, b, cfg.max_source, cfg.d_model)), jnp.float32),
+            "frames": jnp.asarray(RNG.normal(size=(K, S, b, *base.audio_frames(cfg))), jnp.float32),
             "tokens": jnp.asarray(RNG.integers(0, cfg.vocab, (K, S, b, 16)), jnp.int32),
             "weight": jnp.asarray(w),
         }
