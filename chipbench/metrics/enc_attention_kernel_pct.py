@@ -1,0 +1,26 @@
+"""enc_attention_kernel_pct: the share, in %, of the encoder
+self-attention stage's device self-time (``enc_attention_ms``'s events,
+chipbench/scopes_encdec.py) that the stage spends in Pallas kernel calls
+(``tpu_custom_call`` instructions, chipbench/kernels.py), over the
+traced rounds and the cell's chips. The rest is the layout work around
+the kernels: pads, transposes, the backward's rowsum(dO * O). Reads 0
+where the stage runs no kernel; nothing to read without a trace or
+without the stage."""
+from chipbench import kernels, scopes, scopes_encdec
+
+
+def read(rec):
+    t = rec.trace
+    if t is None or not t.get("devices") or not rec.hlo:
+        return None
+    names = scopes.op_scopes(rec.hlo)
+    calls = kernels.custom_calls(rec.hlo)
+    lo, hi = t["window"]
+    stage = kernel = 0.0
+    for events in t["devices"].values():
+        for name, start, own in scopes_encdec.self_times(events):
+            if lo <= start < hi and scopes_encdec.stage(names.get(name, "")) == "enc_attention":
+                stage += own
+                if name in calls:
+                    kernel += own
+    return 100.0 * kernel / stage if stage else None

@@ -2,7 +2,8 @@
 
 - ``rnnt_joint``      — fused RNN-T joint + log-softmax + (blank, label)
                         gather (the paper-model's memory hot-spot)
-- ``flash_attention`` — blockwise causal/window/GQA attention
+- ``flash_attention`` — blockwise causal/window/GQA attention, and the
+                        encoder's self-attention with its own backward
 - ``decode_attention``— flash-decode (one token vs. a long cache)
 - ``lstm_gates``      — fused LSTM cell pointwise update
 - ``wire_pack``       — packed-wire payloads for the compression plane

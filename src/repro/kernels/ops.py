@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention import flash_decode
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import flash_attention_vjp
 from repro.kernels.lstm_gates import lstm_gates_fused
 from repro.kernels.rnnt_joint import rnnt_joint_bwd_fused, rnnt_joint_fused
 
@@ -24,11 +24,12 @@ def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "logit_softcap"))
-def attention(q, k, v, causal: bool = True, window: int = 0, logit_softcap: float = 0.0):
-    return flash_attention(
-        q, k, v, causal=causal, window=window, logit_softcap=logit_softcap, interpret=_on_cpu()
-    )
+@jax.jit
+def attention(q, k, v):
+    """Non-causal attention of equal-length, equal-head q, k, v
+    (B, S, H, D), differentiable: the fused flash kernel and its own
+    backward (``kernels/flash_attention.py``)."""
+    return flash_attention_vjp(q, k, v, interpret=_on_cpu())
 
 
 @functools.partial(jax.jit, static_argnames=("window",))

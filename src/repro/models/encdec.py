@@ -19,6 +19,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
 from repro.models.attention import (
     AttnConfig,
     attn_init,
@@ -146,6 +147,18 @@ def frontend(cfg: EncDecConfig, params, mels):
     return _conv(x, p["conv2_w"], p["conv2_b"], 2)
 
 
+def _flash_path() -> bool:
+    """The encoder's self-attention runs the fused flash kernel off the
+    CPU; on the CPU, ``blockwise_attention``."""
+    return jax.default_backend() != "cpu"
+
+
+def _self_attention(q, k, v):
+    if _flash_path():
+        return ops.attention(q, k, v)
+    return blockwise_attention(q, k, v, causal=False, block_kv=min(512, q.shape[1]))
+
+
 def encode(cfg: EncDecConfig, params, frames):
     """frames: (B, 2 * S, n_mels) log-mel frames, or with ``n_mels == 0``
     (B, S, d_model) embeddings -> (B, S, d_model)."""
@@ -163,7 +176,7 @@ def encode(cfg: EncDecConfig, params, frames):
             h = layer_norm(xc, lp["norm1"], lp["norm1_b"])
             q, k, v = _project_qkv(lp["attn"], acfg, h, jnp.zeros(xc.shape[:2], jnp.int32))
             with jax.named_scope("enc_attention"):
-                o = blockwise_attention(q, k, v, causal=False, block_kv=min(512, xc.shape[1]))
+                o = _self_attention(q, k, v)
             xc = xc + o.reshape(xc.shape[0], xc.shape[1], -1) @ lp["attn"]["wo"].astype(xc.dtype)
             h2 = layer_norm(xc, lp["norm2"], lp["norm2_b"])
             xc = xc + mlp_apply(lp["mlp"], h2, cfg.act)

@@ -31,6 +31,9 @@ TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
         (2, 128, 128, 4, 1, 32, False, 0),
         (1, 512, 512, 2, 2, 64, True, 0),
         (1, 128, 256, 4, 4, 32, False, 0),   # cross-attention shape
+        (2, 150, 150, 2, 2, 64, False, 0),   # encoder head size, 150 = 2 x 64 + 22
+        (1, 300, 300, 4, 4, 64, False, 0),   # 300 = 4 x 64 + 44
+        (1, 200, 200, 4, 2, 32, True, 48),   # partial last tile under causal + window
     ],
 )
 def test_flash_attention_sweep(B, Sq, Sk, H, Kv, D, causal, window, dtype):
@@ -44,6 +47,91 @@ def test_flash_attention_sweep(B, Sq, Sk, H, Kv, D, causal, window, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expected, np.float32),
                                atol=TOL[dtype], rtol=TOL[dtype])
+
+
+# forward and gradient tolerances of the flash VJP against f32 oracles;
+# bf16 operands round P and dS to bf16 (about 2^-9 relative) before
+# their matmuls, so its gradients of order 1 land within 3e-2
+VJP_TOL = {jnp.float32: (2e-5, 1e-4), jnp.bfloat16: (2e-2, 3e-2)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "B,S,H,D",
+    [
+        (2, 150, 2, 64),    # one tile of 150 keys, not a multiple of 8 or 128
+        (1, 600, 2, 64),    # a 512 tile and a partial one of 88
+        (1, 128, 4, 32),
+    ],
+)
+def test_flash_attention_vjp_matches_ref_and_blockwise(B, S, H, D, dtype):
+    """The encoder kernel's forward and its own backward (dq, dk, dv)
+    against the f32 reference and ``blockwise_attention``, which it
+    replaces off the CPU. Interpret mode fills the partial tile's rows
+    past the end with NaN, so any leak of them into a result fails."""
+    from repro.kernels.flash_attention import flash_attention_vjp
+    from repro.models.attention import blockwise_attention
+
+    q, k, v = (_rand((B, S, H, D), dtype, np.random.default_rng(i)) for i in range(3))
+    w = _rand((B, S, H, D), jnp.float32, np.random.default_rng(3))
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    oracles = {
+        "ref": lambda q_, k_, v_: ref.attention_ref(q_, k_, v_, causal=False),
+        "blockwise": lambda q_, k_, v_: blockwise_attention(q_, k_, v_, causal=False,
+                                                            block_kv=min(512, S)),
+    }
+    fwd_tol, grad_tol = VJP_TOL[dtype]
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w)
+
+    def kernel(q_, k_, v_):
+        return flash_attention_vjp(q_, k_, v_, interpret=True)
+
+    out = kernel(q, k, v)
+    grads = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+    assert out.dtype == dtype and all(g.dtype == dtype for g in grads)
+    for name, fn in oracles.items():
+        np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(fn(*f32)),
+                                   atol=fwd_tol, rtol=fwd_tol, err_msg=name)
+        want = jax.grad(loss(fn), argnums=(0, 1, 2))(*f32)
+        for label, got, exp in zip("qkv", grads, want):
+            np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(exp),
+                                       atol=grad_tol, rtol=grad_tol, err_msg=f"{name} d{label}")
+
+
+@pytest.mark.parametrize("transform", ["vmap", "checkpoint"])
+def test_flash_attention_vjp_under_vmap_and_checkpoint(transform):
+    """As ``encode`` runs it: vmapped over the round's clients, and inside
+    each encoder layer's ``jax.checkpoint``; both the value and the
+    gradients match the f32 reference, client by client."""
+    from repro.kernels.flash_attention import flash_attention_vjp
+
+    K, B, S, H, D = 2, 1, 150, 2, 64
+    q, k, v = (_rand((K, B, S, H, D), jnp.float32, np.random.default_rng(i)) for i in range(3))
+    w = _rand((K, B, S, H, D), jnp.float32, np.random.default_rng(3))
+
+    def kernel(q_, k_, v_):
+        return flash_attention_vjp(q_, k_, v_, interpret=True)
+
+    def reference(q_, k_, v_):
+        return ref.attention_ref(q_, k_, v_, causal=False)
+
+    def wrap(fn):
+        if transform == "vmap":
+            return jax.vmap(fn)
+        layer = jax.checkpoint(lambda q_, k_, v_: fn(q_, k_, v_) * 2.0)
+        return jax.vmap(layer)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(wrap(fn)(*a) * w)
+
+    np.testing.assert_allclose(np.asarray(wrap(kernel)(q, k, v)),
+                               np.asarray(wrap(reference)(q, k, v)), atol=2e-5, rtol=2e-5)
+    got = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(reference), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -181,6 +269,41 @@ def test_model_lstm_dispatch_matches_ref_path():
     h2, c2 = lstm_gates(gates, c)
     np.testing.assert_array_equal(np.asarray(h1), np.asarray(h2))
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
+
+
+def test_encode_attention_dispatch(monkeypatch):
+    """models.encdec runs the encoder's self-attention through the flash
+    kernel exactly off the CPU (the backend probe is forced here; the
+    kernel then runs in interpret mode) and on the CPU through
+    ``blockwise_attention`` with the arguments it always had."""
+    from repro.configs import base, get_arch
+    from repro.kernels import ops
+    from repro.models import encdec
+
+    cfg = get_arch("whisper-base").make_smoke_config()
+    params = encdec.init_params(cfg, jax.random.PRNGKey(0))
+    frames = _rand((2, *base.audio_frames(cfg)), jnp.float32)
+    calls = []
+    kernel, blockwise = ops.attention, encdec.blockwise_attention
+
+    def spy_kernel(*a):
+        calls.append(("kernel", {}))
+        return kernel(*a)
+
+    def spy_blockwise(*a, **kw):
+        calls.append(("blockwise", kw))
+        return blockwise(*a, **kw)
+
+    monkeypatch.setattr(ops, "attention", spy_kernel)
+    monkeypatch.setattr(encdec, "blockwise_attention", spy_blockwise)
+    assert not encdec._flash_path()
+    on_cpu = encdec.encode(cfg, params, frames)
+    assert calls == [("blockwise", {"causal": False, "block_kv": cfg.max_source})]
+    calls.clear()
+    monkeypatch.setattr(encdec, "_flash_path", lambda: True)
+    off_cpu = encdec.encode(cfg, params, frames)
+    assert calls == [("kernel", {})]
+    np.testing.assert_allclose(np.asarray(off_cpu), np.asarray(on_cpu), atol=1e-4, rtol=1e-4)
 
 
 def test_blockwise_attention_matches_kernel_oracle():

@@ -66,7 +66,8 @@ def test_w_hh_grad_booked_to_its_layer(tiny):
     assert booked == {"encoder_bwd", "prednet"}
 
 
-def test_encdec_scopes_reach_the_round_step():
+def _encdec_op_names() -> dict:
+    """{instruction: op_name} of the tiny Whisper round step compiled here."""
     import numpy as np
 
     from repro.configs import base, get_arch
@@ -84,7 +85,11 @@ def test_encdec_scopes_reach_the_round_step():
              "label_len": np.full((K, S, b), U, np.int32),
              "weight": np.ones((K, S, b), np.float32)}
     state = engine.init_state(task.bundle.init(jax.random.PRNGKey(0)))
-    names = scopes.op_scopes(jax.jit(engine.step).lower(state, batch).compile().as_text())
+    return scopes.op_scopes(jax.jit(engine.step).lower(state, batch).compile().as_text())
+
+
+def test_encdec_scopes_reach_the_round_step():
+    names = _encdec_op_names()
     parts = {p.split("(")[-1].rstrip(")") for n in names.values() for p in n.split("/")}
     assert {"frontend", "encoder", "enc_attention", "decoder", "lm_loss"} <= parts
     count = {}
@@ -96,3 +101,18 @@ def test_encdec_scopes_reach_the_round_step():
     # what no scope names is nearly all the bodies of reductions (the
     # layer norms', the softmaxes'), which run inside their callers' fusions
     assert count.get(scopes_encdec.UNSCOPED, 0) < len(names) / 5
+
+
+def test_encoder_flash_kernel_counts_to_enc_attention(monkeypatch):
+    """With the encoder on the flash kernel (forced here, in interpret
+    mode), every instruction lowered from the kernel's calls, forward,
+    recompute and backward, lies in the ``enc_attention`` stage."""
+    from repro.models import encdec
+
+    monkeypatch.setattr(encdec, "_flash_path", lambda: True)
+    kernel = [n for n in _encdec_op_names().values() if "/jit(attention)/" in n]
+    backward = [n for n in kernel if "transpose(jvp(encoder))" in n]
+    assert len(backward) < len(kernel)                                 # the forward
+    assert any("rematted_computation" in n for n in backward)          # the layer's recompute
+    assert any("rematted_computation" not in n for n in backward)      # the backward kernel
+    assert {scopes_encdec.stage(n) for n in kernel} == {"enc_attention"}

@@ -109,3 +109,48 @@ def test_dequantize_compiles(one_chip):
 def test_nibble_unpack_compiles(one_chip):
     packed = _spec((N // 2,), jnp.int8, one_chip)
     _assert_kernel(lambda p: wire_pack.nibble_unpack_pallas(p, N), packed)
+
+
+def test_encoder_flash_attention_compiles_vmapped_over_clients(one_chip):
+    """whisper-libri-fp32's encoder attention: K = 8 clients of 4
+    utterances, 1,500 positions, 8 heads of 64, bf16; the forward and the
+    backward kernel, as the local step's gradient runs them."""
+    from repro.kernels.flash_attention import flash_attention_vjp
+
+    x = _spec((8, 4, 1500, 8, 64), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention_vjp(q, k, v).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.vmap(jax.grad(loss, argnums=(0, 1, 2)))).lower(x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_encoder_kernels_count_to_enc_attention(one_chip, monkeypatch):
+    """One whisper-base encoder layer at its published widths, its
+    gradient compiled for the chip: the kernel calls (forward, the
+    layer's recompute, backward) all carry the ``enc_attention`` scope,
+    so the benchmark books them to ``enc_attention_ms``."""
+    import dataclasses
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from chipbench import kernels, scopes, scopes_encdec
+    from repro.configs import get_arch
+    from repro.models import encdec
+
+    cfg = dataclasses.replace(get_arch("whisper-base").make_config(), enc_layers=1)
+    params = jax.eval_shape(lambda: encdec.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), params)
+    frames = _spec((1, 2 * cfg.max_source, cfg.n_mels), jnp.float32, one_chip)
+
+    def loss(p, f):
+        return jnp.sum(encdec.encode(cfg, p, f).astype(jnp.float32))
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = jax.jit(jax.grad(loss)).lower(params, frames).compile().as_text()
+    names = scopes.op_scopes(text)
+    calls = kernels.custom_calls(text)
+    assert len(calls) == 3
+    assert {scopes_encdec.stage(names[c]) for c in calls} == {"enc_attention"}
