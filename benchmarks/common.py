@@ -74,8 +74,8 @@ def csv_row(name: str, us_per_call: float, derived: str) -> str:
 
 def bench_reps(env: str, knob: str) -> int:
     """Rep count for a bench loop: the environment variable wins (CI
-    pins budgets), otherwise the tuner knob (results/tuning.json can
-    retune per device)."""
+    pins budgets), otherwise the named constant of
+    ``repro.profile.tuner``."""
     from repro.profile.tuner import get_knob
 
     raw = os.environ.get(env)

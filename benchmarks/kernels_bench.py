@@ -5,8 +5,9 @@ path) and the jnp blockwise implementations they target.
 Measurement protocol (all benches): interleaved order-rotating reps
 with per-variant MIN, via ``benchmarks.common.interleaved_min_us``
 (the fed_round protocol, shared through ``repro.profile.trace``).
-Rep counts come from tuner knobs (``results/tuning.json``) unless the
-``REPRO_BENCH_*_REPS`` environment pins them.
+Rep counts come from the ``bench.*`` constants of
+``repro.profile.tuner`` unless the ``REPRO_BENCH_*_REPS`` environment
+pins them.
 """
 from __future__ import annotations
 
@@ -101,47 +102,6 @@ def bench_rnnt_joint_bwd():
                   f"pallas_us={t['pallas']:.1f};"
                   f"interp_ratio={t['pallas'] / max(t['chunked'], 1e-9):.2f}"))
     return t["chunked"], t["pallas"]
-
-
-def bench_lstm_scan():
-    """The per-client recurrent hot-spot: one grad step through an LSTM
-    scan (S=32, B=8, H=128 — a kernel-eligible shape). The gated
-    us_per_call is the lax.scan-over-fused-gates CPU production path;
-    the full-scan Pallas kernel's custom-VJP grad runs in interpret
-    mode and lands in the derived column as a relative number only."""
-    from repro.kernels.lstm_gates import lstm_scan_fused_vjp
-    from repro.models.lstm import lstm_gates
-
-    rng = np.random.default_rng(2)
-    S, B, H = 32, 8, 128
-    xg = jnp.asarray(rng.normal(size=(S, B, 4 * H)) * 0.4, jnp.float32)
-    w_hh = jnp.asarray(rng.normal(size=(H, 4 * H)) * 0.1, jnp.float32)
-    h0 = jnp.zeros((B, H), jnp.float32)
-    c0 = jnp.zeros((B, H), jnp.float32)
-
-    def scan_loss(xg, w_hh):
-        def step(carry, xg_t):
-            h, c = carry
-            h, c = lstm_gates(xg_t + h @ w_hh, c)
-            return (h, c), h
-
-        (h, c), ys = jax.lax.scan(step, (h0, c0), xg)
-        return ys.sum() + h.sum() + c.sum()
-
-    def kernel_loss(xg, w_hh):
-        ys, hT, cT = lstm_scan_fused_vjp(xg, w_hh, h0, c0, interpret=True)
-        return ys.sum() + hT.sum() + cT.sum()
-
-    scan_grad = jax.jit(jax.grad(scan_loss, argnums=(0, 1)))
-    kernel_grad = jax.jit(jax.grad(kernel_loss, argnums=(0, 1)))
-    t = interleaved_min_us({"scan": lambda: scan_grad(xg, w_hh),
-                            "kernel": lambda: kernel_grad(xg, w_hh)},
-                           reps=bench_reps("REPRO_BENCH_MICRO_REPS",
-                                           "bench.micro_reps"))
-    print(csv_row("lstm_scan_grad", t["scan"],
-                  f"kernel_us={t['kernel']:.1f};"
-                  f"interp_ratio={t['kernel'] / max(t['scan'], 1e-9):.2f}"))
-    return t["scan"], t["kernel"]
 
 
 def _fed_round_setup():
@@ -422,7 +382,6 @@ def main(trace_path: str = "results/trace_kernels.json") -> tuple[dict, dict]:
     times["attention_blockwise_1k"], _ = bench_attention()
     times["rnnt_joint_chunked"], _ = bench_rnnt_joint()
     times["rnnt_joint_bwd_chunked"], _ = bench_rnnt_joint_bwd()
-    times["lstm_scan_grad"], _ = bench_lstm_scan()
     plane_times, plane_speedups = bench_wire_plane()
     times.update(plane_times)
     round_times, flags = bench_fed_round()

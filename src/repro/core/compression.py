@@ -128,23 +128,6 @@ def tree_param_bytes(tree: PyTree) -> int:
     return sum(int(l.size) * jnp.dtype(l.dtype).itemsize for l in jax.tree.leaves(tree))
 
 
-def wire_cost_profile(cfg: CompressionConfig, tree: PyTree) -> dict:
-    """Static wire-cost profile of one client delta under ``cfg`` — the
-    profiling plane's per-scheme feature block (``repro.profile.predict``
-    attaches it to point predictions): exact uplink bytes, the fp32
-    dense baseline, and the realized compression ratio. Pure arithmetic
-    over leaf sizes, so abstract (``eval_shape``) trees price
-    identically to materialized ones."""
-    up = client_wire_bytes(cfg, tree)
-    dense = _WORD * sum(int(l.size) for l in jax.tree.leaves(tree))
-    return {
-        "kind": cfg.kind,
-        "uplink_bytes": up,
-        "dense_bytes": dense,
-        "ratio": dense / up if up else float("inf"),
-    }
-
-
 # ----------------------------------------------------------------------
 # Codes layer: tensors <-> the integers / (value, index) pairs that a
 # client actually transmits. Both the in-graph and the packed path are

@@ -1,11 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on TPU
-they compile to Mosaic. ``rnnt_joint`` carries a custom_vjp whose
-backward dispatches via the ``rnnt.joint_bwd_dispatch`` tuning knob:
-the fused Pallas backward (recomputing the joint tile in VMEM with the
-forward's shape bucketing) off-CPU, the U-chunked jnp rematerializer
-on CPU — both preserve the forward's O(B·T·U) memory during training.
+On CPU the kernels execute in interpret mode; on TPU they compile to
+Mosaic. ``rnnt_joint`` carries a custom_vjp whose backward is chosen by
+backend: the fused Pallas backward (recomputing the joint tile in VMEM
+with the forward's shape bucketing) off-CPU, the U-chunked jnp
+rematerializer on CPU — both preserve the forward's O(B·T·U) memory
+during training.
 """
 from __future__ import annotations
 
@@ -89,12 +89,8 @@ def _rnnt_joint_fwd(enc_proj, pred_proj, w_out, bias, labels):
 
 
 def _use_joint_bwd_pallas() -> bool:
-    from repro.profile.tuner import get_knob
-
-    mode = get_knob("rnnt.joint_bwd_dispatch")
-    if mode == "pallas":
-        return True
-    return mode == "auto" and not _on_cpu()
+    """The joint's backward runs the Pallas kernels off the CPU."""
+    return not _on_cpu()
 
 
 def _rnnt_joint_bwd(res, cts):

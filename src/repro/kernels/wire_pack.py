@@ -334,24 +334,13 @@ def quantize_pack4_keyed_pallas(x, scale, key_data, *, tile: int = _TILE, interp
 # ---------------------------------------------------- public auto-dispatch
 # Pallas on TPU; the jnp oracle is the CPU production path (interpret
 # mode is for tests only — same convention as repro.kernels.ops). The
-# tuning registry's 'wire_pack.dispatch' knob (repro.profile.tuner)
-# overrides the backend choice per device. The lazy import keeps the
-# kernel layer free of any import-order coupling (tuner is stdlib-only
-# at module level). The top-k scatters are XLA scatters on every
-# backend: Mosaic refuses the one-element dynamic lane stores a Pallas
-# scatter needs.
+# top-k scatters are XLA scatters on every backend: Mosaic refuses the
+# one-element dynamic lane stores a Pallas scatter needs.
 
 
 def _dispatch() -> tuple:
-    """(use_ref, interpret) for this call site, honoring the tuning
-    registry's measured per-device override."""
-    from repro.profile.tuner import get_knob
-
-    mode = get_knob("wire_pack.dispatch")
-    if mode == "ref":
-        return True, False
-    if mode == "pallas":
-        return False, _on_cpu()
+    """(use_ref, interpret) for this call site: the jnp oracle on the
+    CPU, the Pallas kernel compiled for the device elsewhere."""
     return _on_cpu(), False
 
 

@@ -22,8 +22,8 @@ Robustness: HLO text evolves across XLA releases (dynamic ``<=N``
 bounded dims, new narrow dtypes, opcode syntax we have never seen).
 Instructions this parser cannot price degrade to a counted
 ``unparsed_ops`` field on :class:`CostSummary` instead of raising
-mid-parse, so the profiling plane's predictor keeps working on newer
-jax HLO text — consumers decide how much unparsed mass they tolerate.
+mid-parse, so the dry run keeps working on newer jax HLO text —
+consumers decide how much unparsed mass they tolerate.
 """
 
 from __future__ import annotations

@@ -1,22 +1,8 @@
-"""Roofline report: aggregate dry-run JSONs into the §Roofline table,
-plus the profiling plane's predicted-vs-measured fed-round report.
+"""Roofline report: aggregate dry-run JSONs into the §Roofline table.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.roofline --dir results/dryrun \
         [--format md|csv]
-    PYTHONPATH=src python -m repro.launch.roofline --predict [--strict]
-    PYTHONPATH=src python -m repro.launch.roofline --drift \
-        [--baseline results/predict_baseline.json]
-
-``--predict`` calibrates per-device cost coefficients against the five
-tiny-RNN-T acceptance plans (fp32 / int8 / int4_packed / top5 / async),
-prints predicted-vs-measured round seconds for BOTH feature sources
-(closed-form analytic and HLO-derived), persists the coefficients to
-``results/tuning.json`` and the report to ``results/predict_report.json``.
-With ``--strict`` the exit code is nonzero when any plan's relative
-error exceeds the documented tolerance. ``--drift`` re-measures and
-compares against a committed baseline report — warn-only by design
-(machine variance is expected); CI runs it with continue-on-error.
 """
 from __future__ import annotations
 
@@ -24,7 +10,6 @@ import argparse
 import glob
 import json
 import os
-import sys
 
 
 def load_records(d: str):
@@ -102,113 +87,11 @@ def to_csv(recs) -> str:
     return "\n".join(rows)
 
 
-# ----------------------------------------------------------------------
-# Predicted-vs-measured fed-round report (repro.profile.predict)
-# ----------------------------------------------------------------------
-
-def predict_table(report: dict) -> str:
-    """The --predict report as a markdown table."""
-    lines = [
-        "| plan | measured (s) | analytic (s) | err | hlo (s) | err | unparsed |",
-        "|---|---|---|---|---|---|---|",
-    ]
-    for r in report["rows"]:
-        lines.append(
-            f"| {r['plan']} | {r['measured_s']:.4f} "
-            f"| {r['predicted_analytic_s']:.4f} | {r['rel_err_analytic']:.1%} "
-            f"| {r['predicted_hlo_s']:.4f} | {r['rel_err_hlo']:.1%} "
-            f"| {r['unparsed_ops']:.0f} |")
-    m = report["max_rel_err"]
-    lines.append(
-        f"\nmax rel err: analytic={m['analytic']:.1%} hlo={m['hlo']:.1%} "
-        f"(tolerance {report['tolerance']:.0%}) on {report['device_key']}")
-    return "\n".join(lines)
-
-
-def run_predict(reps: int, report_out: str, trace_out: str,
-                strict: bool) -> int:
-    from repro.profile.predict import predict_report
-
-    report = predict_report(reps=reps, trace_path=trace_out)
-    os.makedirs(os.path.dirname(report_out) or ".", exist_ok=True)
-    with open(report_out, "w") as f:
-        json.dump(report, f, indent=1)
-    print(predict_table(report))
-    print(f"[roofline] predict report -> {report_out}")
-    worst = max(report["max_rel_err"].values())
-    if worst > report["tolerance"]:
-        print(f"[roofline] WARNING: max rel err {worst:.1%} exceeds "
-              f"tolerance {report['tolerance']:.0%}")
-        return 1 if strict else 0
-    return 0
-
-
-# Measured round times may drift this factor either way before the
-# (warn-only) drift step flags them: CI runners share a device_key but
-# not load conditions, so the bar is deliberately loose — it exists to
-# catch order-of-magnitude engine regressions, not scheduler noise.
-DRIFT_FACTOR = 2.0
-
-
-def run_drift(baseline_path: str, reps: int, strict: bool) -> int:
-    from repro.profile.predict import predict_report
-
-    if not os.path.exists(baseline_path):
-        print(f"[roofline] no baseline at {baseline_path}; run --predict "
-              "and commit the report to enable drift checks")
-        return 0
-    with open(baseline_path) as f:
-        base = json.load(f)
-    fresh = predict_report(reps=reps, persist_coeffs=False)
-    if fresh["device_key"] != base.get("device_key"):
-        print(f"[roofline] drift skipped: baseline device "
-              f"{base.get('device_key')!r} != current {fresh['device_key']!r}")
-        return 0
-    base_rows = {r["plan"]: r for r in base.get("rows", [])}
-    drifted = []
-    for r in fresh["rows"]:
-        b = base_rows.get(r["plan"])
-        if b is None:
-            continue
-        ratio = r["measured_s"] / max(b["measured_s"], 1e-12)
-        marker = ""
-        if ratio > DRIFT_FACTOR or ratio < 1.0 / DRIFT_FACTOR:
-            drifted.append(r["plan"])
-            marker = "  <-- DRIFT"
-        print(f"[drift] {r['plan']:>12s}: {b['measured_s']:.4f}s -> "
-              f"{r['measured_s']:.4f}s (x{ratio:.2f}){marker}")
-    if drifted:
-        print(f"[roofline] WARNING: round time drifted >x{DRIFT_FACTOR} "
-              f"on {drifted} — refresh results/predict_baseline.json if "
-              "the change is intentional")
-        return 1 if strict else 0
-    print("[roofline] no drift beyond "
-          f"x{DRIFT_FACTOR} across {len(fresh['rows'])} plans")
-    return 0
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--dir", default="results/dryrun")
     ap.add_argument("--format", default="md", choices=["md", "csv"])
-    ap.add_argument("--predict", action="store_true",
-                    help="calibrate + report predicted-vs-measured "
-                         "fed-round seconds on the acceptance plans")
-    ap.add_argument("--drift", action="store_true",
-                    help="re-measure and compare against --baseline "
-                         "(warn-only unless --strict)")
-    ap.add_argument("--reps", type=int, default=3)
-    ap.add_argument("--report-out", default="results/predict_report.json")
-    ap.add_argument("--trace-out", default="results/trace_predict.json")
-    ap.add_argument("--baseline", default="results/predict_baseline.json")
-    ap.add_argument("--strict", action="store_true",
-                    help="exit nonzero on tolerance/drift violations")
     args = ap.parse_args()
-    if args.predict:
-        sys.exit(run_predict(args.reps, args.report_out, args.trace_out,
-                             args.strict))
-    if args.drift:
-        sys.exit(run_drift(args.baseline, args.reps, args.strict))
     recs = load_records(args.dir)
     print(to_markdown(recs) if args.format == "md" else to_csv(recs))
 

@@ -158,8 +158,7 @@ class SweepRunner:
         self.client_eval_examples = client_eval_examples
         # when set, run_point emits one trace JSON per point through
         # the profiling plane's single writer (repro.profile.trace):
-        # host pack / round-step / eval section timers plus the
-        # predictor's static features — the calibration corpus
+        # host pack / round-step / eval section timers
         self.trace_dir = trace_dir
         # mesh_clients > 1: run() shards embarrassingly-parallel grid
         # points over the `clients` mesh (see _run_sharded) — grids are
@@ -356,7 +355,6 @@ class SweepRunner:
                                log=log)
         if self.trace_dir:
             from repro.core.engine import structural_key_str
-            from repro.profile.predict import plan_round_features
             from repro.profile.trace import write_trace
 
             path = os.path.join(self.trace_dir,
@@ -367,11 +365,6 @@ class SweepRunner:
                 sections=rec,
                 counters={"rounds": point.rounds, "n_params": n_params,
                           "local_steps": native, "padded_steps": S},
-                # the predictor's static features for THIS point: each
-                # traced sweep row is a (features, measured round_s)
-                # calibration sample — min_s of "round" is the
-                # steady-state round, free of round-1 compile
-                features=plan_round_features(plan, params, native),
                 meta={"id": point.id, "wall_s": row["wall_s"]},
             )
             log(f"  [trace] {path}")
@@ -560,7 +553,7 @@ class SweepRunner:
             log(f"[sweeps] {len(points)} points padded to S={steps} local "
                 f"steps -> one compiled round fn per engine/optimizer")
         if self.mesh_clients > 1 and not self.trace_dir and not self.client_eval:
-            # trace calibration needs per-point section timers, and the
+            # traces need per-point section timers, and the
             # per-client plane measures after every round — neither fits
             # the lockstep path, so both force sequential
             return self._run_sharded(points, steps, log=log)
@@ -1057,30 +1050,9 @@ def mark_pareto(rows: list[dict], cost="cfmq_tb", quality="quality") -> list[dic
     return rows
 
 
-def predict_grid_costs(runner: SweepRunner, points, axis: str = "cfmq_tb",
-                       coeffs: Optional[dict] = None) -> Dict[str, float]:
-    """Per-point predicted cost on ``axis`` (``cfmq_tb`` | ``seconds``)
-    WITHOUT running anything: features come from ``jax.eval_shape``
-    abstract params, so no device allocation or compilation happens."""
-    from repro.profile.predict import abstract_params, predict_point
-    from repro.profile.tuner import registry
-
-    if coeffs is None and axis == "seconds":
-        coeffs = registry().get_coefficients("analytic")
-    predicted = {}
-    for p in points:
-        _, bundle = runner._bundle(p.specaug_scale)
-        params = abstract_params(bundle, seed=p.seed)
-        pred = predict_point(p.plan, params, steps=runner.native_steps(p.plan),
-                             rounds=p.rounds, coeffs=coeffs)
-        predicted[p.id] = pred["cfmq_tb" if axis == "cfmq_tb" else "point_s"]
-    return predicted
-
-
 def run_grid(grid: str, rounds: Optional[int] = None, smoke: bool = False,
              seed: int = 0, out: Optional[str] = None, runner: Optional[SweepRunner] = None,
              pad_steps: Optional[bool] = None, check: bool = False,
-             prune_budget: Optional[float] = None, prune_axis: str = "cfmq_tb",
              trace_dir: Optional[str] = None, mesh_clients: int = 0,
              population: int = 0, client_eval: int = 0,
              plan_overrides: Optional[dict] = None,
@@ -1091,12 +1063,6 @@ def run_grid(grid: str, rounds: Optional[int] = None, smoke: bool = False,
     compile time dominates, so padding every point to one shape (one
     compilation for the whole grid) wins; at full budgets the padded
     no-op steps cost more than the extra per-shape retraces save.
-
-    ``prune_budget`` turns on the planner: points whose *predicted*
-    cost on ``prune_axis`` exceeds the budget are skipped before any
-    compilation. Under ``--check`` the FULL grid runs anyway and
-    ``repro.profile.tuner.check_prune`` asserts the pruner would have
-    dropped >= 1 point without touching the measured pareto frontier.
     """
     make_points = GRIDS[grid]
     kwargs = dict(grid_kwargs, smoke=smoke, seed=seed)
@@ -1126,23 +1092,6 @@ def run_grid(grid: str, rounds: Optional[int] = None, smoke: bool = False,
                              pad_steps=smoke if pad_steps is None else pad_steps,
                              trace_dir=trace_dir, mesh_clients=mesh_clients,
                              client_eval=client_eval)
-    prune = None
-    if prune_budget is not None:
-        from repro.profile.tuner import prune_report
-
-        predicted = predict_grid_costs(runner, points, axis=prune_axis)
-        prune = prune_report(predicted, prune_budget, prune_axis)
-        dropped = sorted(pid for pid, d in prune.items() if not d.keep)
-        if check:
-            # run everything: --check's job is to PROVE the skip list
-            # would have been safe, which needs the measured rows
-            log(f"[sweeps] prune(--check): would drop {dropped} "
-                f"(predicted {prune_axis} > {prune_budget:g}); running "
-                "full grid to verify the frontier survives")
-        else:
-            points = [p for p in points if prune[p.id].keep]
-            log(f"[sweeps] prune: dropped {dropped} of {len(prune)} "
-                f"points (predicted {prune_axis} > {prune_budget:g})")
     t0 = time.time()
     log(f"[sweeps] grid={grid} points={len(points)} "
         f"rounds={[p.rounds for p in points]}")
@@ -1152,8 +1101,6 @@ def run_grid(grid: str, rounds: Optional[int] = None, smoke: bool = False,
         "n_points": len(rows), "wall_s": time.time() - t0,
         "points": rows,
     }
-    if prune is not None:
-        frontier["prune"] = {pid: d.as_dict() for pid, d in prune.items()}
     out = out or f"results/sweep_{grid}.json"
     os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
     with open(out, "w") as f:
@@ -1161,14 +1108,10 @@ def run_grid(grid: str, rounds: Optional[int] = None, smoke: bool = False,
     log(f"[sweeps] frontier ({sum(r['pareto'] for r in rows)} pareto points) "
         f"-> {out} [{frontier['wall_s']:.0f}s]")
     if check:
-        if prune is not None:
-            from repro.profile.tuner import check_prune
-
-            check_prune(rows, prune, log=log)
         checker = GRID_CHECKS.get(grid)
-        if checker is None and prune is None:
+        if checker is None:
             log(f"[sweeps] no --check defined for grid {grid!r}; skipping")
-        elif checker is not None:
+        else:
             checker(frontier, log=log)
     return frontier
 
@@ -1188,19 +1131,10 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="assert the grid's qualitative claim after the "
                          "run (e.g. robustness: trimmed_mean beats "
-                         "weighted_mean under sign_flip@0.3); with "
-                         "--prune-budget, also prove the pruner never "
-                         "drops a measured-pareto point")
-    ap.add_argument("--prune-budget", type=float, default=None,
-                    help="skip points whose PREDICTED cost on "
-                         "--prune-axis exceeds this budget, before "
-                         "anything compiles (repro.profile planner)")
-    ap.add_argument("--prune-axis", default="cfmq_tb",
-                    choices=("cfmq_tb", "seconds"))
+                         "weighted_mean under sign_flip@0.3)")
     ap.add_argument("--trace-dir", default=None,
                     help="emit one trace JSON per point (pack/round/eval "
-                         "section timers + predictor features) into this "
-                         "directory")
+                         "section timers) into this directory")
     from repro.launch.cli import (
         add_client_eval_args,
         add_plan_args,
@@ -1217,7 +1151,6 @@ def main():
     use_compile_cache()
     run_grid(args.grid, rounds=args.rounds, smoke=args.smoke, seed=args.seed,
              out=args.out, pad_steps=args.pad_steps, check=args.check,
-             prune_budget=args.prune_budget, prune_axis=args.prune_axis,
              trace_dir=args.trace_dir, mesh_clients=args.mesh_clients,
              population=args.population, client_eval=args.client_eval,
              plan_overrides=plan_overrides(args))

@@ -125,7 +125,7 @@ def run_federated(
 
     ``trace_path`` routes pack/round/eval section timers through the
     profiling plane's single writer (``repro.profile.trace``), keyed by
-    the engine's structural key — the train-side calibration feed.
+    the engine's structural key.
     ``mesh_clients`` > 0 shards the round's client axis over a
     ``clients`` mesh (bit-for-bit the vmap round on 1 device).
     ``client_eval`` > 0 tracks that many clients' per-round
@@ -201,8 +201,8 @@ def run_federated(
     staleness = []
     # per-shard prefetch: with a client mesh the worker thread puts each
     # round batch pre-split over the ``clients`` axis, so the sharded
-    # round step never stalls on a consumer-thread reshard; depth comes
-    # from the tuning registry (``prefetch.depth``)
+    # round step never stalls on a consumer-thread reshard; depth is
+    # the ``prefetch.depth`` constant
     batch_sharding = None
     if client_sharding is not None:
         from jax.sharding import NamedSharding, PartitionSpec
@@ -297,7 +297,6 @@ def run_federated(
     )
     if trace_path:
         from repro.core.engine import structural_key_str
-        from repro.profile.predict import plan_round_features
         from repro.profile.trace import write_trace
 
         write_trace(
@@ -306,8 +305,6 @@ def run_federated(
             sections=rec,
             counters={"rounds": rounds, "n_params": n_params,
                       "local_steps": sampler.steps},
-            features=plan_round_features(plan, params, sampler.steps,
-                                         client_shards=mesh_clients or 1),
             meta={"wall_s": train_time_s, "final_loss": history["final_loss"]},
         )
         log(f"[trace] {trace_path}")

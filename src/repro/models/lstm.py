@@ -6,9 +6,8 @@ state update, the Pallas ``lstm_gates`` kernel's target (ref path
 inline here). Sequence iteration is ``lax.scan`` under a custom VJP
 whose backward carries only (dh, dc) through the reversed time loop and
 forms the recurrent weight's gradient after it, as one matmul over all
-S*B rows: ``dW_hh = sum_t h_{t-1}^T dgates_t``. On TPU, eligible shapes
-instead run the full-scan Pallas kernel, which keeps w_hh and its
-gradient VMEM-resident. Multi-layer stacks loop per layer.
+S*B rows: ``dW_hh = sum_t h_{t-1}^T dgates_t``. Multi-layer stacks loop
+per layer.
 """
 from __future__ import annotations
 
@@ -60,45 +59,32 @@ def _fused_tile(h: int):
     return None
 
 
+def _gate_kernel_path() -> bool:
+    """The cell's gate math runs the fused Pallas kernel off the CPU;
+    on the CPU, the jnp ``lstm_gates``."""
+    return jax.default_backend() != "cpu"
+
+
 def _lstm_gates_dispatch(gates: jnp.ndarray, c: jnp.ndarray):
     """Off-CPU: the Pallas fused kernel with its fused custom-VJP
     backward (the cell's backward dominates the federated round's
     per-client scan). CPU: the jnp reference — same math, XLA-fused,
     and what every parity test pins. The one shape gate is H % 128: a
     hidden size that does not tile into 128-lane blocks keeps the
-    reference on every backend."""
-    if jax.default_backend() == "cpu" or c.shape[-1] % 128:
+    reference on every backend. Forced on the CPU, the kernel runs in
+    interpret mode."""
+    if not _gate_kernel_path() or c.shape[-1] % 128:
         return lstm_gates(gates, c)
     th = _fused_tile(c.shape[-1])
     from repro.kernels.lstm_gates import lstm_gates_fused_vjp
 
-    return lstm_gates_fused_vjp(gates, c, th=th)
+    return lstm_gates_fused_vjp(gates, c, th=th, interpret=jax.default_backend() == "cpu")
 
 
 def lstm_cell_step(p, x, h, c):
     """x: (B, d_in); h, c: (B, d_hidden)."""
     gates = x @ p["w_ih"].astype(x.dtype) + h @ p["w_hh"].astype(x.dtype) + p["b"].astype(x.dtype)
     return lstm_gates(gates, c)
-
-
-def _scan_kernel_eligible(S: int, d_h: int, chunk: int) -> bool:
-    """Static shape gate for the full-scan Pallas kernel, resolved
-    against the tuning registry: lane-tileable hidden size, sequence
-    long enough that the per-step w_hh refetch dominates, and the
-    resident (H x 4H) weight within the VMEM budget. ``chunk`` requests
-    gradient-checkpointed scanning the kernel does not implement, so it
-    always keeps lax.scan."""
-    from repro.profile.tuner import get_knob
-
-    mode = get_knob("lstm.scan_dispatch")
-    if mode == "ref" or chunk:
-        return False
-    if d_h % 128 != 0:
-        return False
-    whh_mb = d_h * 4 * d_h * 4 / 2**20
-    if S < int(get_knob("lstm.scan_min_seq")) or whh_mb > float(get_knob("lstm.scan_max_vmem_mb")):
-        return False
-    return mode == "pallas" or jax.default_backend() != "cpu"
 
 
 def _lstm_step(w_hh, carry, xg_t):
@@ -108,7 +94,7 @@ def _lstm_step(w_hh, carry, xg_t):
     return (h, c), h
 
 
-def _lstm_scan(w_hh, xg, h0, c0, unroll):
+def _recurrence_scan(w_hh, xg, h0, c0, unroll):
     """xg: (S, B, 4h) time-major input pre-activations -> ys (S, B, h),
     final (h, c). ``h0`` None is the zero initial state."""
     if h0 is None:
@@ -117,13 +103,13 @@ def _lstm_scan(w_hh, xg, h0, c0, unroll):
     return ys, h, c
 
 
-_lstm_recurrence = jax.custom_vjp(_lstm_scan, nondiff_argnums=(4,))
+_lstm_recurrence = jax.custom_vjp(_recurrence_scan, nondiff_argnums=(4,))
 
 
 def _lstm_recurrence_fwd(w_hh, xg, h0, c0, unroll):
     # w_hh is closed over, not differentiated: the transposed scan then
     # carries only (dh, dc) and reads w_hh as a constant.
-    out, vjp = jax.vjp(lambda xg, h0, c0: _lstm_scan(w_hh, xg, h0, c0, unroll), xg, h0, c0)
+    out, vjp = jax.vjp(lambda xg, h0, c0: _recurrence_scan(w_hh, xg, h0, c0, unroll), xg, h0, c0)
     return out, (w_hh, h0, out[0], vjp)
 
 
@@ -157,31 +143,13 @@ def lstm_layer(p, xs, h0=None, c0=None, unroll: int = 1, chunk: int = 0):
     ``dW_hh`` is then one float32-accumulated matmul contracted over
     (S, B), plus ``h0``'s term where an initial state is given, under
     the ``w_hh_grad`` scope. ``chunk`` runs the
-    gradient-checkpointed scan under plain autodiff instead.
-
-    On TPU, eligible shapes dispatch the full-scan Pallas kernel instead
-    (``lstm_scan_fused``): the whole sequence runs in ONE pallas_call
-    whose w_hh block is fetched once and stays VMEM-resident for all S
-    steps, with a fused reversed-scan custom-VJP backward that
-    recomputes the gate preactivations in VMEM and keeps dW_hh in VMEM
-    (thresholds in the tuning registry; `--autotune lstm` re-measures
-    them)."""
-    B, S, _ = xs.shape
+    gradient-checkpointed scan under plain autodiff instead."""
+    B = xs.shape[0]
     d_h = p["w_hh"].shape[0]
     h = jnp.zeros((B, d_h), xs.dtype) if h0 is None else h0
     c = jnp.zeros((B, d_h), jnp.float32) if c0 is None else c0
     # hoist the input matmul out of the scan (one big MXU matmul)
     xg = xs @ p["w_ih"].astype(xs.dtype) + p["b"].astype(xs.dtype)  # (B, S, 4h)
-
-    if _scan_kernel_eligible(S, d_h, chunk):
-        from repro.kernels.lstm_gates import lstm_scan_fused_vjp
-        from repro.profile.tuner import get_knob
-
-        interpret = get_knob("lstm.scan_dispatch") == "pallas" and jax.default_backend() == "cpu"
-        ys, hT, cT = lstm_scan_fused_vjp(
-            xg.swapaxes(0, 1), p["w_hh"], h, c.astype(jnp.float32), interpret=interpret
-        )
-        return ys.swapaxes(0, 1), (hT.astype(xs.dtype), cT)
 
     if chunk:
         from repro.models.layers import chunked_scan

@@ -1,29 +1,22 @@
-"""Profiling plane: trace capture, fed-round cost prediction, and the
-autotuning planner.
-
-Three layers (see each module's docstring):
+"""Profiling plane: trace capture and the host loops' named constants.
 
 - ``repro.profile.trace`` — versioned trace JSON from real runs; one
   writer for train / sweeps / bench, keyed by the RoundEngine
-  structural key + a device fingerprint.
-- ``repro.profile.predict`` — static FLOP/byte features x per-device
-  least-squares coefficients: price any FederatedPlan without running
-  it.
-- ``repro.profile.tuner`` — the registry that owns kernel dispatch
-  thresholds (measured overrides persist to results/tuning.json) and
-  the predicted-cost sweep-grid pruner.
+  structural key + a device fingerprint; host spans on the profiler's
+  clock; the interleaved min-of-reps timing protocol.
+- ``repro.profile.tuner`` — the constant table behind ``get_knob``
+  (prefetch depth, bench rep counts).
 
-Submodules are imported lazily: the kernel layer reads tuner knobs
-from its dispatch path, so this package must be importable mid-way
-through ``repro.core`` / ``repro.kernels`` imports without touching
-them back.
+Submodules are imported lazily, so this package is importable mid-way
+through ``repro.core`` / ``repro.data`` imports without touching them
+back.
 """
 
 from __future__ import annotations
 
 import importlib
 
-_SUBMODULES = ("predict", "trace", "tuner")
+_SUBMODULES = ("trace", "tuner")
 
 
 def __getattr__(name: str):

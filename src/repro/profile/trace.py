@@ -3,14 +3,14 @@ train / sweeps / bench, and the program's host spans on the profiler's
 clock.
 
 Every emitter — the train driver's round loop, the sweep runner's
-per-point timing, the kernel micro benches, the roofline predictor's
-report — produces the SAME record shape through :func:`write_trace`
+per-point timing, the kernel micro benches — produces the SAME record
+shape through :func:`write_trace`
 (mirroring how ``repro.core.metrics`` owns one summary-row schema for
 train/sweeps/bench):
 
 - ``schema_version`` / ``kind``: one of :data:`TRACE_KINDS`;
-- ``device`` + ``device_key``: the fingerprint that keys tuning.json —
-  coefficients calibrated on one machine never silently price another;
+- ``device`` + ``device_key``: the fingerprint of the machine that
+  took the timings, so records from two machines are told apart;
 - ``structural_key``: the RoundEngine jit-cache identity of the traced
   plan (``repro.core.engine.structural_key_str``), so traces join
   against compiled-graph identities, not point names;
@@ -18,11 +18,7 @@ train/sweeps/bench):
   mean_s}) from a :class:`TraceRecorder` wrapped around the host
   pipeline stages (pack -> round step -> eval);
 - ``kernels``: per-kernel us from the micro benches;
-- ``features`` + ``counters``: the predictor's static per-round cost
-  features and run bookkeeping (rounds, n_params, ...).
-
-The records are for reading; the predictor calibrates from its own
-interleaved measurements (``predict.predict_report``), not from them.
+- ``counters``: run bookkeeping (rounds, n_params, ...).
 
 Host spans: :func:`span` is a ``jax.profiler.TraceAnnotation`` named
 ``repro.<name>``, and every :class:`TraceRecorder` section opens one,
@@ -33,8 +29,8 @@ the jitted round step the stages are ``jax.named_scope``s instead
 device operation.
 
 Also home to :func:`measure_interleaved_min` — the order-rotating
-min-of-reps protocol of the fed_round bench, shared here so benches and
-the predictor measure the same way.
+min-of-reps protocol of the fed_round bench, shared here so every
+bench measures the same way.
 """
 
 from __future__ import annotations
@@ -49,8 +45,8 @@ from typing import Callable, Optional
 
 import jax
 
-TRACE_SCHEMA_VERSION = 1
-TRACE_KINDS = ("round", "sweep", "kernels", "predict")
+TRACE_SCHEMA_VERSION = 2
+TRACE_KINDS = ("round", "sweep", "kernels")
 SECTION_STAT_KEYS = ("count", "total_s", "min_s", "mean_s")
 
 
@@ -72,8 +68,8 @@ def device_fingerprint() -> dict:
 
 
 def device_key(fp: Optional[dict] = None) -> str:
-    """Stable slug of the fingerprint — the tuning.json / trace join
-    key (e.g. ``cpu_x8_cpu_x86_64_jax0.4.37``)."""
+    """Stable slug of the fingerprint — the traces' join key (e.g.
+    ``cpu_x8_cpu_x86_64_jax0.9.0``)."""
     fp = fp or device_fingerprint()
     raw = (
         f"{fp['backend']}_x{fp['device_count']}_{fp['device_kind']}"
@@ -139,7 +135,6 @@ def trace_record(
     sections: Optional[dict] = None,
     kernels: Optional[dict] = None,
     counters: Optional[dict] = None,
-    features: Optional[dict] = None,
     meta: Optional[dict] = None,
 ) -> dict:
     """Build a schema-valid trace record (the one writer's payload)."""
@@ -153,7 +148,6 @@ def trace_record(
         "sections": dict(sections or {}),
         "kernels": {k: float(v) for k, v in (kernels or {}).items()},
         "counters": {k: float(v) for k, v in (counters or {}).items()},
-        "features": {k: float(v) for k, v in (features or {}).items()},
         "meta": dict(meta or {}),
     }
     return validate_trace(rec)
@@ -177,7 +171,6 @@ def validate_trace(rec: dict) -> dict:
         "sections",
         "kernels",
         "counters",
-        "features",
         "meta",
     )
     missing = [k for k in required if k not in rec]

@@ -318,104 +318,6 @@ def test_blockwise_attention_matches_kernel_oracle():
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5)
 
 
-# ------------------------------------------------- full LSTM scan (PR 10)
-
-
-def _lstm_scan_ref(xg, w_hh, h0, c0):
-    """lax.scan over the jnp gate math — what the kernel must match.
-    xg: (S, B, 4H) hoisted input projections."""
-    from repro.models.lstm import lstm_gates
-
-    def step(carry, xg_t):
-        h, c = carry
-        gates = xg_t + h @ w_hh.astype(xg_t.dtype)
-        h, c = lstm_gates(gates, c)
-        return (h, c), h
-
-    (h, c), ys = jax.lax.scan(step, (h0, c0), xg)
-    return ys, h, c
-
-
-def _lstm_scan_case(S, B, H, seed=0):
-    rng = np.random.default_rng(seed)
-    xg = jnp.asarray(rng.normal(size=(S, B, 4 * H)) * 0.5, jnp.float32)
-    w_hh = jnp.asarray(rng.normal(size=(H, 4 * H)) * 0.3, jnp.float32)
-    h0 = jnp.asarray(rng.normal(size=(B, H)) * 0.1, jnp.float32)
-    c0 = jnp.asarray(rng.normal(size=(B, H)) * 0.1, jnp.float32)
-    return xg, w_hh, h0, c0
-
-
-@pytest.mark.parametrize("S,B,H", [(1, 2, 8), (5, 3, 8), (12, 2, 16),
-                                   (32, 1, 8)])
-def test_lstm_scan_kernel_matches_scan(S, B, H):
-    from repro.kernels.lstm_gates import lstm_scan_fused
-
-    xg, w_hh, h0, c0 = _lstm_scan_case(S, B, H, seed=S)
-    ys, cs = lstm_scan_fused(xg, w_hh, h0, c0, interpret=True)
-    ys_r, hT_r, cT_r = _lstm_scan_ref(xg, w_hh, h0, c0)
-    np.testing.assert_allclose(np.asarray(ys), np.asarray(ys_r), atol=2e-6)
-    np.testing.assert_allclose(np.asarray(cs[-1]), np.asarray(cT_r), atol=2e-6)
-
-
-@pytest.mark.parametrize("S,B,H", [(1, 2, 8), (5, 3, 8), (12, 2, 16)])
-def test_lstm_scan_vjp_matches_scan_grads(S, B, H):
-    """The reversed-scan backward kernel: gradients wrt ALL inputs match
-    autodiff through lax.scan (the kernel recomputes gates in VMEM; the
-    reference rematerializes via XLA)."""
-    from repro.kernels.lstm_gates import lstm_scan_fused_vjp
-
-    xg, w_hh, h0, c0 = _lstm_scan_case(S, B, H, seed=100 + S)
-    wy = jnp.asarray(np.random.default_rng(5).normal(size=(S, B, H)),
-                     jnp.float32)
-
-    def f_kernel(xg, w_hh, h0, c0):
-        ys, hT, cT = lstm_scan_fused_vjp(xg, w_hh, h0, c0, interpret=True)
-        return (ys * wy).sum() + 1.7 * hT.sum() + 0.9 * cT.sum()
-
-    def f_ref(xg, w_hh, h0, c0):
-        ys, hT, cT = _lstm_scan_ref(xg, w_hh, h0, c0)
-        return (ys * wy).sum() + 1.7 * hT.sum() + 0.9 * cT.sum()
-
-    gk = jax.grad(f_kernel, argnums=(0, 1, 2, 3))(xg, w_hh, h0, c0)
-    gr = jax.grad(f_ref, argnums=(0, 1, 2, 3))(xg, w_hh, h0, c0)
-    for name, a, r in zip(("dxg", "dw_hh", "dh0", "dc0"), gk, gr):
-        denom = float(jnp.abs(r).max()) + 1e-30
-        np.testing.assert_allclose(np.asarray(a) / denom,
-                                   np.asarray(r) / denom,
-                                   atol=1e-5, err_msg=name)
-
-
-def test_lstm_layer_scan_dispatch_parity():
-    """models.lstm.lstm_layer under the forced-Pallas tuner knob equals
-    the lax.scan path — outputs and grads through a full layer (w_ih,
-    w_hh, b all differentiated)."""
-    from repro.models.lstm import lstm_cell_init, lstm_layer
-    from repro.profile import tuner
-
-    B, S, D, H = 2, 16, 12, 128
-    p = lstm_cell_init(jax.random.PRNGKey(0), D, H)
-    xs = jnp.asarray(np.random.default_rng(2).normal(size=(B, S, D)),
-                     jnp.float32)
-
-    def loss(p, xs):
-        ys, (h, c) = lstm_layer(p, xs)
-        return (ys ** 2).sum() + h.sum() + c.sum()
-
-    reg = tuner.TuningRegistry(path="/tmp/test_lstm_dispatch_tuning.json")
-    tuner.set_registry(reg)
-    try:
-        reg.set_override("lstm.scan_dispatch", "ref")
-        l_ref, g_ref = jax.value_and_grad(loss)(p, xs)
-        reg.set_override("lstm.scan_dispatch", "pallas")
-        l_k, g_k = jax.value_and_grad(loss)(p, xs)
-    finally:
-        tuner.set_registry(None)
-    np.testing.assert_allclose(float(l_k), float(l_ref), rtol=1e-6)
-    for k in g_ref:
-        np.testing.assert_allclose(np.asarray(g_k[k]), np.asarray(g_ref[k]),
-                                   rtol=2e-5, atol=2e-5, err_msg=k)
-
-
 # ----------------------------------------- fused RNN-T joint bwd (PR 10)
 
 
@@ -486,14 +388,25 @@ def test_rnnt_joint_bwd_fused_matches_chunked_ref(B, T, U1, J, V, tq, tu, tv):
                                    atol=5e-5, err_msg=name)
 
 
-def test_rnnt_joint_custom_vjp_pallas_dispatch_multichunk():
-    """End-to-end: ops.rnnt_joint with the joint-backward knob forced to
-    the Pallas kernels matches plain-jnp reference grads — on a
-    multi-chunk U1 so the dispatch covers the shape class the chunked
-    path buckets."""
-    from repro.kernels.ops import rnnt_joint
-    from repro.profile import tuner
+@pytest.mark.parametrize("forced", [False, True], ids=["backend", "kernel"])
+def test_rnnt_joint_custom_vjp_pallas_dispatch_multichunk(monkeypatch, forced):
+    """End-to-end: ops.rnnt_joint's gradients match plain-jnp reference
+    grads with its backward chosen by backend (the chunked jnp
+    backward on the CPU) and with the Pallas backward forced through
+    the joint's backend predicate — on a multi-chunk U1 so the dispatch
+    covers the shape class the chunked path buckets."""
+    from repro.kernels import ops
 
+    assert not ops._use_joint_bwd_pallas()
+    if forced:
+        monkeypatch.setattr(ops, "_use_joint_bwd_pallas", lambda: True)
+    pallas, calls = ops.rnnt_joint_bwd_fused, []
+
+    def spy(*args, **kwargs):
+        calls.append(kwargs)
+        return pallas(*args, **kwargs)
+
+    monkeypatch.setattr(ops, "rnnt_joint_bwd_fused", spy)
     e, g, w, b, lbl = _joint_case(2, 32, 24, 16, 64, seed=11)
 
     def f(fn):
@@ -502,13 +415,8 @@ def test_rnnt_joint_custom_vjp_pallas_dispatch_multichunk():
             return (bb * 1.3 + ll).sum()
         return jax.grad(loss, argnums=(0, 1, 2, 3))(e, g, w, b)
 
-    reg = tuner.TuningRegistry(path="/tmp/test_joint_dispatch_tuning.json")
-    tuner.set_registry(reg)
-    try:
-        reg.set_override("rnnt.joint_bwd_dispatch", "pallas")
-        gk = f(rnnt_joint)
-    finally:
-        tuner.set_registry(None)
+    gk = f(ops.rnnt_joint)
+    assert calls == ([{"interpret": True}] if forced else [])
     gr = f(ref.rnnt_joint_ref)
     for name, a, r in zip(("de", "dg", "dw", "db"), gk, gr):
         denom = float(jnp.abs(r).max()) + 1e-30

@@ -202,29 +202,6 @@ def test_sharding_requires_divisible_cohort():
                            client_sharding=sh)
 
 
-# -------------------------------------------- predictor sharded layout
-
-def test_predictor_sharded_features():
-    """Per-shard compute, invariant client wire bytes, a ring-psum ICI
-    term that is exactly zero on one device (so unsharded calibration
-    and every committed coefficient set stay valid)."""
-    from repro.profile import predict
-
-    params = {"w": np.zeros((64, 32), np.float32)}
-    plan = FederatedPlan(clients_per_round=8, local_batch_size=4)
-    f1 = predict.plan_round_features(plan, params, steps=3)
-    f8 = predict.plan_round_features(plan, params, steps=3, client_shards=8)
-    assert f1["ici_bytes"] == 0.0
-    assert f8["flops"] == f1["flops"] / 8
-    assert f8["hbm_bytes"] == f1["hbm_bytes"] / 8
-    assert f8["wire_bytes"] == f1["wire_bytes"]     # uplink is per-client
-    assert f8["ici_bytes"] == 2.0 * (7 / 8) * 4.0 * (64 * 32)
-    # pre-sharding feature dicts (no ici_bytes key) must stay loadable
-    legacy = {k: v for k, v in f1.items() if k != "ici_bytes"}
-    assert predict.predict_round_seconds(legacy) == \
-        predict.predict_round_seconds(f1)
-
-
 # ------------------------------------------------- virtual populations
 
 def _vp(n_clients=1_000_000, seed=1):
