@@ -39,19 +39,24 @@ def bench_attention():
     return t["block"], t["naive"]
 
 
-def bench_rnnt_joint():
-    """The paper-model hot-spot: fused (chunked) vs naive materialized joint."""
-    rng = np.random.default_rng(1)
+def _joint_bench_case(seed):
+    rng = np.random.default_rng(seed)
     B, T, U1, J, V = 4, 128, 24, 64, 512
     e = jnp.asarray(rng.normal(size=(B, T, J)), jnp.float32)
     g = jnp.asarray(rng.normal(size=(B, U1, J)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(J, V)) * 0.3, jnp.float32)
     b = jnp.asarray(rng.normal(size=(V,)) * 0.1, jnp.float32)
     lbl = jnp.asarray(rng.integers(0, V, (B, U1)), jnp.int32)
+    return (B, T, U1, J, V), (e, g, w, b, lbl)
 
-    from repro.kernels.ops import _joint_ref_chunked
 
-    chunked = jax.jit(lambda *a: _joint_ref_chunked(*a))
+def bench_rnnt_joint():
+    """The paper-model hot-spot: the chunked jnp joint (the CPU path) vs
+    the naive materialized joint."""
+    from repro.models.rnnt import joint_chunked
+
+    (B, T, U1, J, V), (e, g, w, b, lbl) = _joint_bench_case(1)
+    chunked = jax.jit(lambda *a: joint_chunked(*a))
     naive = jax.jit(lambda e, g, w, b, l: ref.rnnt_joint_ref(e, g, w, b, l))
     t = interleaved_min_us({"chunked": lambda: chunked(e, g, w, b, lbl),
                             "naive": lambda: naive(e, g, w, b, lbl)},
@@ -66,37 +71,31 @@ def bench_rnnt_joint():
 
 
 def bench_rnnt_joint_bwd():
-    """The joint's *backward* at the same bench shapes: the U-chunked
-    jnp rematerializing VJP (CPU production path, gated) vs the fused
-    Pallas backward that recomputes the joint tile in VMEM
-    (interpret mode here — relative number in the derived column)."""
-    from repro.kernels.ops import _joint_ref_chunked
-    from repro.kernels.rnnt_joint import rnnt_joint_bwd_fused, rnnt_joint_fused
+    """The joint's backward, with the forward it needs, at the same bench
+    shapes: autodiff through the chunked jnp joint (the CPU path) vs the
+    fused kernels (``kernels/rnnt_joint.py``; interpret mode here, so
+    their time is only a relative number in the derived column). Every
+    frame is held."""
+    from repro.kernels.rnnt_joint import rnnt_joint
+    from repro.models.rnnt import joint_chunked
 
+    (B, T, U1, J, V), (e, g, w, b, lbl) = _joint_bench_case(3)
     rng = np.random.default_rng(3)
-    B, T, U1, J, V = 4, 128, 24, 64, 512
-    e = jnp.asarray(rng.normal(size=(B, T, J)), jnp.float32)
-    g = jnp.asarray(rng.normal(size=(B, U1, J)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(J, V)) * 0.3, jnp.float32)
-    b = jnp.asarray(rng.normal(size=(V,)) * 0.1, jnp.float32)
-    lbl = jnp.asarray(rng.integers(0, V, (B, U1)), jnp.int32)
-    dbl = jnp.asarray(rng.normal(size=(B, T, U1)), jnp.float32)
-    dlb = jnp.asarray(rng.normal(size=(B, T, U1)), jnp.float32)
-    _, _, lse = rnnt_joint_fused(e, g, w, b, lbl, interpret=True,
-                                 return_lse=True)
-    jax.block_until_ready(lse)
+    cts = (jnp.asarray(rng.normal(size=(B, T, U1)), jnp.float32),
+           jnp.asarray(rng.normal(size=(B, T, U1)), jnp.float32))
+    frames = jnp.full((B,), T, jnp.int32)
 
-    def chunked_bwd(e, g, w, b, dbl, dlb):
-        _, vjp = jax.vjp(
-            lambda e_, g_, w_, b_: _joint_ref_chunked(e_, g_, w_, b_, lbl),
-            e, g, w, b)
-        return vjp((dbl, dlb))
+    def backward(joint):
+        def f(e, g, w, b, cts):
+            _, vjp = jax.vjp(joint, e, g, w, b)
+            return vjp(cts)
+        return jax.jit(f)
 
-    chunked = jax.jit(chunked_bwd)
-    pallas = jax.jit(lambda *a: rnnt_joint_bwd_fused(*a, interpret=True))
+    chunked = backward(lambda *a: joint_chunked(*a, lbl))
+    pallas = backward(lambda *a: rnnt_joint(*a, lbl, frames, interpret=True))
     t = interleaved_min_us(
-        {"chunked": lambda: chunked(e, g, w, b, dbl, dlb),
-         "pallas": lambda: pallas(e, g, w, b, lbl, lse, dbl, dlb)},
+        {"chunked": lambda: chunked(e, g, w, b, cts),
+         "pallas": lambda: pallas(e, g, w, b, cts)},
         reps=bench_reps("REPRO_BENCH_MICRO_REPS", "bench.micro_reps"))
     print(csv_row("rnnt_joint_bwd_chunked", t["chunked"],
                   f"pallas_us={t['pallas']:.1f};"

@@ -8,9 +8,10 @@ init) through the same entry points ``launch.train --preset full``
 uses, in one process:
 
   (a) kernel parity on the chip: the fused LSTM gate kernel (forward and
-      custom-VJP backward) against the jnp cell at H=1152, and the keyed
-      int8 / int4 quantize kernels against the ``kernels.ref`` hash,
-      bit-exact at n = 1152*4608 (one ``w_hh``);
+      custom-VJP backward) against the jnp cell at H=1152, the fused RNN-T
+      joint (forward and gradient) against the float32 dense joint at J=640,
+      V=4096, and the keyed int8 / int4 quantize kernels against the
+      ``kernels.ref`` hash, bit-exact at n = 1152*4608 (one ``w_hh``);
   (b) three fp32 FedAvg rounds through ``run_federated`` (K=8 clients,
       local batch 4): finite losses, exact wire bytes;
   (c) one int8 round, which takes the code-domain fast path, so the
@@ -59,6 +60,7 @@ from repro.core.task import arch_task  # noqa: E402
 from repro.data import FederatedSampler  # noqa: E402
 from repro.kernels import ref, wire_pack  # noqa: E402
 from repro.kernels.lstm_gates import lstm_gates_fused_vjp  # noqa: E402
+from repro.kernels.rnnt_joint import rnnt_joint  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.launch.mesh import make_federated_mesh  # noqa: E402
 from repro.launch.train import run_federated  # noqa: E402
@@ -136,6 +138,8 @@ def phase_kernels() -> None:
         _close(f"B={B} dgates (bf16)", dgk, dgr, atol=2**-6, rtol=2**-7)
         _close(f"B={B} dc_prev (f32)", dck, dcr, atol=1e-4, rtol=1e-4)
 
+    _joint_parity()
+
     n = H * 4 * H
     x = jax.random.normal(jax.random.PRNGKey(7), (n,), jnp.float32)
     key = jax.random.PRNGKey(1234)
@@ -153,6 +157,45 @@ def phase_kernels() -> None:
         bad = int(np.sum(got != want))
         log(f"  keyed int{bits} quantize, n={n}: {bad} of {got.size} bytes differ from ref")
         check(got.shape == want.shape and bad == 0, f"keyed int{bits} kernel != ref hash")
+
+
+def _joint_parity() -> None:
+    """The fused joint at the config's widths, bf16 operands, on frame
+    counts that leave whole tiles past two utterances: log-probs at every
+    frame held, and the gradient of a loss that reads only those, against
+    the dense joint in float32 on the same rounded inputs."""
+    B, T, U1, J, V = 3, 64, 61, 640, 4096
+    ke, kg, kw, kb, kl, kc = jax.random.split(jax.random.PRNGKey(18), 6)
+    e = (0.5 * jax.random.normal(ke, (B, T, J))).astype(jnp.bfloat16)
+    g = (0.5 * jax.random.normal(kg, (B, U1, J))).astype(jnp.bfloat16)
+    w = jax.random.normal(kw, (J, V), jnp.float32) * J ** -0.5
+    b = 0.1 * jax.random.normal(kb, (V,), jnp.float32)
+    lbl = jax.random.randint(kl, (B, U1), 1, V)
+    frames = jnp.array([T, 29, 1])
+    live = (jnp.arange(T)[None, :, None] < frames[:, None, None]).astype(jnp.float32)
+    cts = tuple(jax.random.normal(kc, (2, B, T, U1)) * live)
+
+    def kern(e, g, w, b):
+        return rnnt_joint(e, g, w, b, lbl, frames)
+
+    def dense(e, g, w, b):
+        return ref.rnnt_joint_ref(e.astype(jnp.float32), g.astype(jnp.float32),
+                                  w.astype(jnp.bfloat16).astype(jnp.float32), b, lbl)
+
+    def run(fn):
+        out, vjp = jax.vjp(fn, e, g, w, b)
+        return out, vjp(cts)
+
+    check(_runs_kernel(lambda *a: run(kern), e), "rnnt_joint compiled without its kernel")
+    got = jax.jit(lambda: run(kern))()
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda: run(dense))()
+    for name, k, r in zip(("blank_lp", "label_lp"), got[0], want[0]):
+        _close(f"joint {name} (f32)", k * live, r * live, atol=2e-2, rtol=0)
+    for name, k, r in zip(("de", "dg", "dW", "db"), got[1], want[1]):
+        scale = float(jnp.max(jnp.abs(r)))
+        _close(f"joint {name} / max |{name}|", k.astype(jnp.float32) / scale, r / scale,
+               atol=2e-2, rtol=0)
 
 
 # ------------------------------------------------------- phases (b), (c)

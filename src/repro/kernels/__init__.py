@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the perf-critical compute layers.
 
 - ``rnnt_joint``      — fused RNN-T joint + log-softmax + (blank, label)
-                        gather (the paper-model's memory hot-spot)
+                        log-probs, with its own backward (the
+                        paper-model's memory hot-spot)
 - ``flash_attention`` — blockwise causal/window/GQA attention, and the
                         encoder's self-attention with its own backward
 - ``decode_attention``— flash-decode (one token vs. a long cache)

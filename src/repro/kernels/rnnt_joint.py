@@ -1,25 +1,33 @@
-"""Fused RNN-T joint Pallas TPU kernel — the paper-model's memory wall.
+"""Fused RNN-T joint Pallas TPU kernel, forward and backward.
 
-The naive joint materializes (B, T, U1, V) logits in HBM (V = 4096
-word-pieces): for the paper's batches that tensor dwarfs everything
-else in the step and its HBM round-trip dominates. On TPU this is a
-capacity/bandwidth problem (not a CUDA-occupancy one), so the
-adaptation is VMEM-resident fusion: tile the (T, U1) lattice, and for
-each tile stream V in MXU-aligned slabs, computing
+The joint's logits are (B, T, U1, V) (V = 4096 word-pieces); the
+transducer loss reads two numbers of each lattice point: the blank's
+and the next label's log-probability. The kernels keep the logits in
+VMEM and write only those, with the log-sum-exp as the backward's
+recompute anchor.
 
-    h      = tanh(e_t + g_u)            (tq, tu, J)   VMEM scratch
-    logits = h @ Wo[:, v0:v1] + b       (tq, tu, tv)  transient
-    m, l   : running max / sum-exp      (tq, tu)      VMEM scratch
-    blank  = logits[..., 0]             (tq, tu)
-    label  = logits[..., labels[u]]     one-hot within the slab
+A tile is ``tq`` frames of one utterance by all of its U1 label slots:
 
-and emitting only blank/label log-probs (B, T, U1, 2) — a V/2 (=2048x)
-reduction in joint HBM traffic. Grid: (B, T/tq, U1/tu, V/tv) with the
-vocab axis innermost/sequential carrying the scratch.
+    h      = tanh(e_t + g_u)              (tq * U1, J)  bf16 MXU operand
+    logits = h @ Wo[:, slab] + b[slab]     (tq * U1, tv) float32, per slab
+    lse    : online over the V slabs       float32
+    blank, label = h . w_pick + b_pick     the two picked columns, float32
 
-Backward: wrapped in ``jax.custom_vjp`` whose bwd re-materializes
-through the U-chunked jnp reference (rematerialization keeps the
-memory win during training); see ops.py.
+``w_pick`` (B, 2, U1, J) holds column 0 (the blank) and column
+``labels[u]`` of Wo for each label slot. The wrapper gathers it with jnp,
+so autodiff scatters its cotangent back into dWo and the kernels never
+gather.
+
+Each utterance's frame count rides in as an SMEM block: a tile past it
+computes nothing. The loss gives those lattice points a zero cotangent,
+so the forward writes zeros there and the backward adds nothing. The
+counts are an ordinary input, not scalar prefetch, so that vmap over
+clients adds a grid axis rather than a loop of calls.
+
+The backward is one kernel: it recomputes h and the slab logits once
+from the saved log-sum-exp and produces dh (then de, and dg summed in a
+per-utterance block) and dWo, db, accumulated in blocks that stay in
+VMEM over the whole grid. With the forward, that is four matmul units.
 """
 from __future__ import annotations
 
@@ -30,305 +38,263 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _kernel(e_ref, g_ref, wo_ref, b_ref, lbl_ref,
-            blank_ref, label_ref, lse_ref,
-            h_ref, m_ref, l_ref, blk_ref, lab_ref, *,
-            tv: int, n_v: int):
-    vi = pl.program_id(3)
-
-    @pl.when(vi == 0)
-    def _init():
-        h_ref[...] = jnp.tanh(
-            e_ref[0].astype(jnp.float32)[:, None, :]
-            + g_ref[0].astype(jnp.float32)[None, :, :])
-        m_ref[...] = jnp.full_like(m_ref, -1.0e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        blk_ref[...] = jnp.zeros_like(blk_ref)
-        lab_ref[...] = jnp.zeros_like(lab_ref)
-
-    h = h_ref[...]  # (tq, tu, J)
-    wo = wo_ref[...].astype(jnp.float32)  # (J, tv)
-    logits = jax.lax.dot_general(
-        h.reshape(-1, h.shape[-1]), wo,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(h.shape[0], h.shape[1], tv) + b_ref[...].astype(jnp.float32)
-
-    # running log-sum-exp over the vocab axis
-    m_prev, l_prev = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-    p = jnp.exp(logits - m_new[..., None])
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1)
-    m_ref[...] = m_new
-
-    # blank logit lives in vocab slab 0, column 0
-    @pl.when(vi == 0)
-    def _blank():
-        blk_ref[...] = logits[..., 0]
-
-    # label logit: labels[u] may fall in this slab
-    lbl = lbl_ref[0]  # (tu,) int32
-    col = lbl - vi * tv  # position within slab
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (logits.shape[1], tv), 1)
-              == col[:, None]).astype(jnp.float32)  # (tu, tv)
-    lab_ref[...] += jnp.einsum("quv,uv->qu", logits, onehot)
-
-    @pl.when(vi == n_v - 1)
-    def _finalize():
-        lse = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
-        blank_ref[0] = (blk_ref[...] - lse).astype(blank_ref.dtype)
-        label_ref[0] = (lab_ref[...] - lse).astype(label_ref.dtype)
-        lse_ref[0] = lse.astype(lse_ref.dtype)
+TQ = 8  # frames a tile
+TV = 512  # vocabulary columns a slab
+U_ALIGN = 16  # label slots padded to a bf16 sublane tile
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
-def rnnt_joint_fused(
-    enc_proj: jnp.ndarray,  # (B, T, J)  enc @ W_enc
-    pred_proj: jnp.ndarray,  # (B, U1, J) pred @ W_pred
-    w_out: jnp.ndarray,  # (J, V)
-    bias: jnp.ndarray,  # (V,)
-    labels: jnp.ndarray,  # (B, U1) int32 (labels[:, -1] unused)
-    *,
-    tq: int = 16,
-    tu: int = 8,
-    tv: int = 512,
-    interpret: bool = False,
-    return_lse: bool = False,
+def _hidden(e_ref, g_ref):
+    """h = tanh(e + g) of the tile, (tq, U1, J) float32."""
+    e = e_ref[0].astype(jnp.float32)
+    g = g_ref[0].astype(jnp.float32)
+    return jnp.tanh(e[:, None, :] + g[None, :, :])
+
+
+def _slab_logits(hb, w_ref, b_ref, cols):
+    logits = jnp.dot(hb, w_ref[:, cols], preferred_element_type=jnp.float32)
+    return logits + b_ref[:, cols]
+
+
+def _fwd_kernel(
+    len_ref, e_ref, g_ref, w_ref, b_ref, wp_ref, bp_ref, blank_ref, label_ref, lse_ref, *, tq, tv
 ):
-    """Returns (blank_lp, label_lp): (B, T, U1) log-probs.
+    bi, ti = pl.program_id(0), pl.program_id(1)
+    live = ti * tq < len_ref[bi, 0]
 
-    With ``return_lse`` also returns the per-lattice-point log-sum-exp
-    (B, T, U1) — the backward kernels' recompute anchor (they rebuild
-    softmax probabilities from the saved lse without a second max
-    pass over the vocab axis)."""
-    B, T, J = enc_proj.shape
-    U1 = pred_proj.shape[1]
-    V = w_out.shape[1]
-    tq, tu, tv = min(tq, T), min(tu, U1), min(tv, V)
-    assert T % tq == 0 and U1 % tu == 0 and V % tv == 0, (T, tq, U1, tu, V, tv)
-    n_v = V // tv
+    @pl.when(live)
+    def _compute():
+        h = _hidden(e_ref, g_ref)  # (tq, U1, J)
+        U1, J = h.shape[1:]
+        hb = h.astype(w_ref.dtype).reshape(tq * U1, J)
+        m = l = None
+        for v0 in range(0, w_ref.shape[1], tv):
+            s = _slab_logits(hb, w_ref, b_ref, slice(v0, v0 + tv)).reshape(tq, U1, tv)
+            m_s = jnp.max(s, axis=-1)
+            if m is None:
+                m = m_s
+                l = jnp.sum(jnp.exp(s - m[..., None]), axis=-1)
+            else:
+                m_new = jnp.maximum(m, m_s)
+                l = l * jnp.exp(m - m_new) + jnp.sum(jnp.exp(s - m_new[..., None]), axis=-1)
+                m = m_new
+        lse = m + jnp.log(l)
+        wp = wp_ref[0].astype(jnp.float32)  # (2, U1, J)
+        bp = bp_ref[0]  # (2, U1)
+        blank_ref[0] = jnp.sum(h * wp[0][None], axis=-1) + bp[0:1] - lse
+        label_ref[0] = jnp.sum(h * wp[1][None], axis=-1) + bp[1:2] - lse
+        lse_ref[0] = lse
 
-    bias2d = bias.reshape(1, V)
-    grid = (B, T // tq, U1 // tu, n_v)
-    blank, label, lse = pl.pallas_call(
-        functools.partial(_kernel, tv=tv, n_v=n_v),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, tq, J), lambda b, ti, ui, vi: (b, ti, 0)),
-            pl.BlockSpec((1, tu, J), lambda b, ti, ui, vi: (b, ui, 0)),
-            pl.BlockSpec((J, tv), lambda b, ti, ui, vi: (0, vi)),
-            pl.BlockSpec((1, tv), lambda b, ti, ui, vi: (0, vi)),
-            pl.BlockSpec((1, tu), lambda b, ti, ui, vi: (b, ui)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tq, tu), lambda b, ti, ui, vi: (b, ti, ui)),
-            pl.BlockSpec((1, tq, tu), lambda b, ti, ui, vi: (b, ti, ui)),
-            pl.BlockSpec((1, tq, tu), lambda b, ti, ui, vi: (b, ti, ui)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, U1), jnp.float32),
-            jax.ShapeDtypeStruct((B, T, U1), jnp.float32),
-            jax.ShapeDtypeStruct((B, T, U1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tq, tu, J), jnp.float32),
-            pltpu.VMEM((tq, tu), jnp.float32),
-            pltpu.VMEM((tq, tu), jnp.float32),
-            pltpu.VMEM((tq, tu), jnp.float32),
-            pltpu.VMEM((tq, tu), jnp.float32),
-        ],
-        interpret=interpret,
-    )(enc_proj, pred_proj, w_out, bias2d, labels.astype(jnp.int32))
-    if return_lse:
-        return blank, label, lse
-    return blank, label
+    @pl.when(jnp.logical_not(live))
+    def _skip():
+        blank_ref[...] = jnp.zeros_like(blank_ref)
+        label_ref[...] = jnp.zeros_like(label_ref)
+        lse_ref[...] = jnp.zeros_like(lse_ref)
 
 
-def _dlogits(h, wo_ref, b_ref, lse, dbl, dlb, lbl, vi, tv):
-    """Softmax-cotangent slab shared by both backward kernels.
+def _bwd_kernel(
+    len_ref,
+    e_ref,
+    g_ref,
+    w_ref,
+    b_ref,
+    wp_ref,
+    lse_ref,
+    dbl_ref,
+    dlb_ref,
+    de_ref,
+    dg_ref,
+    dw_ref,
+    db_ref,
+    dwp_ref,
+    dbp_ref,
+    *,
+    tq,
+    tv,
+):
+    bi, ti = pl.program_id(0), pl.program_id(1)
 
-    dlogits_v = dblank * [v == 0] + dlabel * [v == labels[u]]
-              - (dblank + dlabel) * p_v,     p_v = exp(logits_v - lse)
-
-    The deltas are built as iota one-hots against the slab-local column
-    index, so slabs not containing the blank (col 0) or the label column
-    contribute only the -p_v term."""
-    tq, tu = h.shape[0], h.shape[1]
-    wo = wo_ref[...].astype(jnp.float32)  # (J, tv)
-    logits = jax.lax.dot_general(
-        h.reshape(-1, h.shape[-1]), wo,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(tq, tu, tv) + b_ref[...].astype(jnp.float32)
-    p = jnp.exp(logits - lse[..., None])  # (tq, tu, tv)
-
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tu, tv), 1)
-    blank_oh = (iota == -vi * tv).astype(jnp.float32)  # col 0, slab 0
-    label_oh = (iota == (lbl - vi * tv)[:, None]).astype(jnp.float32)
-    d = (-(dbl + dlb)[..., None] * p
-         + dbl[..., None] * blank_oh[None]
-         + dlb[..., None] * label_oh[None])  # (tq, tu, tv)
-    return d
-
-
-def _bwd_eg_kernel(e_ref, g_ref, wo_ref, b_ref, lbl_ref, lse_ref,
-                   dbl_ref, dlb_ref,
-                   de_ref, dgp_ref,
-                   h_ref, dh_ref, *, tv: int, n_v: int):
-    """Backward wrt the encoder/prediction projections.
-
-    Grid (B, T/tq, U1/tu, V/tv), vocab innermost: dh accumulates over
-    vocab slabs in VMEM scratch; at the last slab the tanh backward
-    turns it into dpre, which folds into the (b, ti)-resident de block
-    (accumulated across the whole U axis while the block stays in VMEM)
-    and the per-(ti, ui) dg partial (summed over T outside — the dg
-    output block leaves residency between ti revisits, so in-kernel
-    accumulation over T would be unsound)."""
-    ui = pl.program_id(2)
-    vi = pl.program_id(3)
-
-    @pl.when(jnp.logical_and(ui == 0, vi == 0))
-    def _zero_de():
-        de_ref[...] = jnp.zeros_like(de_ref)
-
-    @pl.when(vi == 0)
-    def _init():
-        h_ref[...] = jnp.tanh(
-            e_ref[0].astype(jnp.float32)[:, None, :]
-            + g_ref[0].astype(jnp.float32)[None, :, :])
-        dh_ref[...] = jnp.zeros_like(dh_ref)
-
-    h = h_ref[...]  # (tq, tu, J)
-    d = _dlogits(h, wo_ref, b_ref, lse_ref[0],
-                 dbl_ref[0], dlb_ref[0], lbl_ref[0], vi, tv)
-    dh_ref[...] += jax.lax.dot_general(
-        d.reshape(-1, tv), wo_ref[...].astype(jnp.float32),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(h.shape)
-
-    @pl.when(vi == n_v - 1)
-    def _finalize():
-        dpre = dh_ref[...] * (1.0 - h * h)  # (tq, tu, J)
-        de_ref[0] += jnp.sum(dpre, axis=1)  # (tq, J)
-        dgp_ref[0, 0] = jnp.sum(dpre, axis=0)  # (tu, J)
-
-
-def _bwd_w_kernel(e_ref, g_ref, wo_ref, b_ref, lbl_ref, lse_ref,
-                  dbl_ref, dlb_ref,
-                  dw_ref, db_ref, *, tv: int):
-    """Backward wrt the output projection / bias.
-
-    Grid (V/tv, B, T/tq, U1/tu), vocab OUTERMOST: the (J, tv) dW slab
-    and (1, tv) db slab stay VMEM-resident while the whole (b, t, u)
-    lattice streams past, so each vocab slab is accumulated exactly once
-    with no HBM-revisit hazard (the mirror of the eg-kernel's ordering,
-    which must keep vocab innermost for the lse recompute)."""
-    bi = pl.program_id(1)
-    ti = pl.program_id(2)
-    ui = pl.program_id(3)
-    vi = pl.program_id(0)
-
-    @pl.when(jnp.logical_and(bi == 0, jnp.logical_and(ti == 0, ui == 0)))
-    def _zero():
+    @pl.when((bi == 0) & (ti == 0))
+    def _zero_weights():
         dw_ref[...] = jnp.zeros_like(dw_ref)
         db_ref[...] = jnp.zeros_like(db_ref)
 
-    h = jnp.tanh(
-        e_ref[0].astype(jnp.float32)[:, None, :]
-        + g_ref[0].astype(jnp.float32)[None, :, :])  # (tq, tu, J)
-    d = _dlogits(h, wo_ref, b_ref, lse_ref[0],
-                 dbl_ref[0], dlb_ref[0], lbl_ref[0], vi, tv)
-    dw_ref[...] += jax.lax.dot_general(
-        h.reshape(-1, h.shape[-1]), d.reshape(-1, tv),
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (J, tv)
-    db_ref[0] += jnp.sum(d, axis=(0, 1))  # (tv,)
+    @pl.when(ti == 0)
+    def _zero_utterance():
+        dg_ref[...] = jnp.zeros_like(dg_ref)
+        dwp_ref[...] = jnp.zeros_like(dwp_ref)
+        dbp_ref[...] = jnp.zeros_like(dbp_ref)
+
+    live = ti * tq < len_ref[bi, 0]
+
+    @pl.when(live)
+    def _compute():
+        h = _hidden(e_ref, g_ref)  # (tq, U1, J)
+        U1, J = h.shape[1:]
+        M = tq * U1
+        dbl, dlb = dbl_ref[0], dlb_ref[0]  # (tq, U1)
+        neg_c = -(dbl + dlb)[..., None]  # d logits = dbl [v=0] + dlb [v=label] - c p
+        hb = h.astype(w_ref.dtype).reshape(M, J)
+        hc = (neg_c * h).astype(w_ref.dtype).reshape(M, J)
+        lse = lse_ref[0][..., None]
+        dh = jnp.zeros((M, J), jnp.float32)
+        for v0 in range(0, w_ref.shape[1], tv):
+            cols = slice(v0, v0 + tv)
+            p = jnp.exp(_slab_logits(hb, w_ref, b_ref, cols).reshape(tq, U1, tv) - lse)
+            pb = p.reshape(M, tv).astype(w_ref.dtype)
+            dh += jax.lax.dot_general(pb, w_ref[:, cols], _NT, preferred_element_type=jnp.float32)
+            dw_ref[:, cols] += jax.lax.dot_general(hc, pb, _TN, preferred_element_type=jnp.float32)
+            db_ref[:, cols] += jnp.sum(neg_c * p, axis=(0, 1))[None]
+        wp = wp_ref[0].astype(jnp.float32)  # (2, U1, J)
+        dh = neg_c * dh.reshape(tq, U1, J) + dbl[..., None] * wp[0] + dlb[..., None] * wp[1]
+        dpre = dh * (1.0 - h * h)
+        de_ref[0] = jnp.sum(dpre, axis=1)
+        dg_ref[0] += jnp.sum(dpre, axis=0)
+        dwp_ref[0, 0] += jnp.sum(dbl[..., None] * h, axis=0)
+        dwp_ref[0, 1] += jnp.sum(dlb[..., None] * h, axis=0)
+        dbp_ref[0] += jnp.stack([jnp.sum(dbl, axis=0), jnp.sum(dlb, axis=0)])
+
+    @pl.when(jnp.logical_not(live))
+    def _skip():
+        de_ref[...] = jnp.zeros_like(de_ref)
 
 
-def rnnt_joint_bwd_fused(
-    enc_proj: jnp.ndarray,  # (B, T, J)
-    pred_proj: jnp.ndarray,  # (B, U1, J)
-    w_out: jnp.ndarray,  # (J, V)
-    bias: jnp.ndarray,  # (V,)
-    labels: jnp.ndarray,  # (B, U1) int32
-    lse: jnp.ndarray,  # (B, T, U1) saved by the forward
-    dblank: jnp.ndarray,  # (B, T, U1) cotangent of blank_lp
-    dlabel: jnp.ndarray,  # (B, T, U1) cotangent of label_lp
-    *,
-    tq: int = 16,
-    tu: int = 8,
-    tv: int = 512,
-    interpret: bool = False,
+def _vmem_limit(J, V, M, tv, backward):
+    """Bytes of VMEM a call needs: Wo resident and double-buffered, in
+    the backward dWo too (float32), the tile's (M, J) arrays and the
+    slab temporaries, and 8 MiB for the small blocks and the compiler."""
+    w = 2 * J * V * 2 + (2 * J * V * 4 if backward else 0)
+    tile = M * J * 4 * (5 if backward else 2) + 4 * M * tv * 4
+    return w + tile + (8 << 20)
+
+
+def _specs(tq, U1, J, V):
+    return (
+        pl.BlockSpec(memory_space=pltpu.SMEM),  # frame counts (B, 1)
+        pl.BlockSpec((1, tq, J), lambda b, t: (b, t, 0)),  # e, de
+        pl.BlockSpec((1, U1, J), lambda b, t: (b, 0, 0)),  # g, dg
+        pl.BlockSpec((J, V), lambda b, t: (0, 0)),  # Wo, dWo
+        pl.BlockSpec((1, V), lambda b, t: (0, 0)),  # bias, db
+        pl.BlockSpec((1, 2, U1, J), lambda b, t: (b, 0, 0, 0)),  # w_pick
+        pl.BlockSpec((1, 2, U1), lambda b, t: (b, 0, 0)),  # b_pick
+        pl.BlockSpec((1, tq, U1), lambda b, t: (b, t, 0)),  # lattice
+    )
+
+
+def joint_forward(e, g, w, bias, w_pick, b_pick, frames, *, tq=TQ, tv=TV, interpret=False):
+    """blank and label log-probs and the log-sum-exp, each (B, T, U1)
+    float32, zero at the frames of tiles wholly past ``frames``.
+
+    e (B, T, J) and g (B, U1, J) in the compute dtype with T % tq == 0 and
+    U1 % 16 == 0; w (J, V) in the compute dtype, bias (V,) float32; w_pick
+    (B, 2, U1, J) the blank's and each slot's label column of w, b_pick
+    (B, 2, U1) theirs of bias; frames (B,) int32."""
+    B, T, J = e.shape
+    U1, V = g.shape[1], w.shape[1]
+    tv = min(tv, V)
+    lengths, frames_s, slots, whole, row, picks, pick_rows, lattice = _specs(tq, U1, J, V)
+    out = jax.ShapeDtypeStruct((B, T, U1), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, tq=tq, tv=tv),
+        grid=(B, T // tq),
+        in_specs=[lengths, frames_s, slots, whole, row, picks, pick_rows],
+        out_specs=[lattice, lattice, lattice],
+        out_shape=[out, out, out],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_vmem_limit(J, V, tq * U1, tv, backward=False),
+        ),
+        interpret=interpret,
+    )(frames.reshape(B, 1), e, g, w, bias.reshape(1, V), w_pick, b_pick)
+
+
+def joint_backward(
+    e, g, w, bias, w_pick, frames, lse, dblank, dlabel, *, tq=TQ, tv=TV, interpret=False
 ):
-    """Fused-backward of :func:`rnnt_joint_fused`.
+    """Cotangents of :func:`joint_forward`'s two log-probs, all float32:
+    de (B, T, J), dg (B, U1, J), dw (J, V), db (V,), dw_pick (B, 2, U1, J),
+    db_pick (B, 2, U1). Tiles past ``frames`` must carry zero cotangents:
+    they add nothing."""
+    B, T, J = e.shape
+    U1, V = g.shape[1], w.shape[1]
+    tv = min(tv, V)
+    lengths, frames_s, slots, whole, row, picks, pick_rows, lattice = _specs(tq, U1, J, V)
+    f32 = jnp.float32
+    de, dg, dw, db, dwp, dbp = pl.pallas_call(
+        functools.partial(_bwd_kernel, tq=tq, tv=tv),
+        grid=(B, T // tq),
+        in_specs=[lengths, frames_s, slots, whole, row, picks, lattice, lattice, lattice],
+        out_specs=[frames_s, slots, whole, row, picks, pick_rows],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, T, J), f32),
+            jax.ShapeDtypeStruct((B, U1, J), f32),
+            jax.ShapeDtypeStruct((J, V), f32),
+            jax.ShapeDtypeStruct((1, V), f32),
+            jax.ShapeDtypeStruct((B, 2, U1, J), f32),
+            jax.ShapeDtypeStruct((B, 2, U1), f32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(J, V, tq * U1, tv, backward=True),
+        ),
+        interpret=interpret,
+    )(frames.reshape(B, 1), e, g, w, bias.reshape(1, V), w_pick, lse, dblank, dlabel)
+    return de, dg, dw, db.reshape(V), dwp, dbp
 
-    Recomputes the joint tile (h = tanh(e + g), slab logits) in VMEM
-    with the same (tq, tu, tv) bucketing as the forward — the (B, T,
-    U1, V) logits tensor never exists in HBM in either direction.
-    Returns (d_enc_proj, d_pred_proj, d_w_out, d_bias) in float32."""
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _joint(e, g, w, bias, w_pick, b_pick, frames, tq, tv, interpret):
+    return _joint_fwd(e, g, w, bias, w_pick, b_pick, frames, tq, tv, interpret)[0]
+
+
+def _joint_fwd(e, g, w, bias, w_pick, b_pick, frames, tq, tv, interpret):
+    wc, b32 = w.astype(e.dtype), bias.astype(jnp.float32)
+    blank, label, lse = joint_forward(
+        e, g, wc, b32, w_pick, b_pick.astype(jnp.float32), frames, tq=tq, tv=tv, interpret=interpret
+    )
+    return (blank, label), (e, g, w, bias, w_pick, b_pick, frames, lse)
+
+
+def _joint_bwd(tq, tv, interpret, res, cts):
+    e, g, w, bias, w_pick, b_pick, frames, lse = res
+    wc, b32 = w.astype(e.dtype), bias.astype(jnp.float32)
+    de, dg, dw, db, dwp, dbp = joint_backward(
+        e, g, wc, b32, w_pick, frames, lse, *cts, tq=tq, tv=tv, interpret=interpret
+    )
+    return (
+        de.astype(e.dtype),
+        dg.astype(g.dtype),
+        dw.astype(w.dtype),
+        db.astype(bias.dtype),
+        dwp.astype(w_pick.dtype),
+        dbp.astype(b_pick.dtype),
+        None,
+    )
+
+
+_joint.defvjp(_joint_fwd, _joint_bwd)
+
+
+def rnnt_joint(enc_proj, pred_proj, w_out, bias, labels, frames, *, tq=TQ, tv=TV, interpret=False):
+    """(blank_lp, label_lp), each (B, T, U1) float32, differentiable in
+    the first four arguments.
+
+    enc_proj (B, T, J) and pred_proj (B, U1, J) in the compute dtype, the
+    MXU's operand type; w_out (J, V) and bias (V,) as the parameters are
+    kept (their gradients come back in that type); labels (B, U1) int
+    (the last slot unused); frames (B,) each utterance's frame count.
+    The lattice is padded to tq frames and 16 label slots; frames past an
+    utterance's count read 0 when a whole tile lies past it, and any
+    other value otherwise: the transducer loss reads neither."""
     B, T, J = enc_proj.shape
     U1 = pred_proj.shape[1]
-    V = w_out.shape[1]
-    tq, tu, tv = min(tq, T), min(tu, U1), min(tv, V)
-    assert T % tq == 0 and U1 % tu == 0 and V % tv == 0, (T, tq, U1, tu, V, tv)
-    n_v = V // tv
-
-    bias2d = bias.reshape(1, V)
-    labels = labels.astype(jnp.int32)
-
-    de, dg_part = pl.pallas_call(
-        functools.partial(_bwd_eg_kernel, tv=tv, n_v=n_v),
-        grid=(B, T // tq, U1 // tu, n_v),
-        in_specs=[
-            pl.BlockSpec((1, tq, J), lambda b, ti, ui, vi: (b, ti, 0)),
-            pl.BlockSpec((1, tu, J), lambda b, ti, ui, vi: (b, ui, 0)),
-            pl.BlockSpec((J, tv), lambda b, ti, ui, vi: (0, vi)),
-            pl.BlockSpec((1, tv), lambda b, ti, ui, vi: (0, vi)),
-            pl.BlockSpec((1, tu), lambda b, ti, ui, vi: (b, ui)),
-            pl.BlockSpec((1, tq, tu), lambda b, ti, ui, vi: (b, ti, ui)),
-            pl.BlockSpec((1, tq, tu), lambda b, ti, ui, vi: (b, ti, ui)),
-            pl.BlockSpec((1, tq, tu), lambda b, ti, ui, vi: (b, ti, ui)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tq, J), lambda b, ti, ui, vi: (b, ti, 0)),
-            pl.BlockSpec((1, 1, tu, J), lambda b, ti, ui, vi: (b, ti, ui, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, J), jnp.float32),
-            jax.ShapeDtypeStruct((B, T // tq, U1, J), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tq, tu, J), jnp.float32),
-            pltpu.VMEM((tq, tu, J), jnp.float32),
-        ],
-        interpret=interpret,
-    )(enc_proj, pred_proj, w_out, bias2d, labels, lse, dblank, dlabel)
-    dg = jnp.sum(dg_part, axis=1)  # (B, U1, J)
-
-    dw, db2d = pl.pallas_call(
-        functools.partial(_bwd_w_kernel, tv=tv),
-        grid=(n_v, B, T // tq, U1 // tu),
-        in_specs=[
-            pl.BlockSpec((1, tq, J), lambda vi, b, ti, ui: (b, ti, 0)),
-            pl.BlockSpec((1, tu, J), lambda vi, b, ti, ui: (b, ui, 0)),
-            pl.BlockSpec((J, tv), lambda vi, b, ti, ui: (0, vi)),
-            pl.BlockSpec((1, tv), lambda vi, b, ti, ui: (0, vi)),
-            pl.BlockSpec((1, tu), lambda vi, b, ti, ui: (b, ui)),
-            pl.BlockSpec((1, tq, tu), lambda vi, b, ti, ui: (b, ti, ui)),
-            pl.BlockSpec((1, tq, tu), lambda vi, b, ti, ui: (b, ti, ui)),
-            pl.BlockSpec((1, tq, tu), lambda vi, b, ti, ui: (b, ti, ui)),
-        ],
-        out_specs=[
-            pl.BlockSpec((J, tv), lambda vi, b, ti, ui: (0, vi)),
-            pl.BlockSpec((1, tv), lambda vi, b, ti, ui: (0, vi)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((J, V), jnp.float32),
-            jax.ShapeDtypeStruct((1, V), jnp.float32),
-        ],
-        interpret=interpret,
-    )(enc_proj, pred_proj, w_out, bias2d, labels, lse, dblank, dlabel)
-    return de, dg, dw, db2d.reshape(V)
+    pad_t, pad_u = -T % tq, -U1 % U_ALIGN
+    e = jnp.pad(enc_proj, ((0, 0), (0, pad_t), (0, 0)))
+    g = jnp.pad(pred_proj, ((0, 0), (0, pad_u), (0, 0)))
+    lbl = jnp.pad(labels.astype(jnp.int32), ((0, 0), (0, pad_u)))
+    ids = jnp.stack([jnp.zeros_like(lbl), lbl], axis=1)  # (B, 2, U1p): blank, label
+    w_pick = jnp.moveaxis(w_out[:, ids], 0, -1).astype(e.dtype)
+    frames = frames.astype(jnp.int32)
+    blank, label = _joint(e, g, w_out, bias, w_pick, bias[ids], frames, tq, tv, interpret)
+    return blank[:, :T, :U1], label[:, :T, :U1]

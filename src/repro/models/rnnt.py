@@ -3,9 +3,9 @@ encoder (prediction network), joint network, softmax over word-pieces.
 
 The joint is the memory hot-spot: naive evaluation materializes
 (B, T, U+1, V) logits (V=4096 in the paper). The training path
-computes only the (blank, label) log-probs the transducer DP needs —
-either via the fused Pallas kernel (repro/kernels/rnnt_joint.py) or
-the U-chunked jnp reference here.
+computes only the (blank, label) log-probs the transducer DP needs:
+off the CPU the fused Pallas kernel (repro/kernels/rnnt_joint.py), on
+the CPU the U-chunked jnp path here, which is also its oracle.
 """
 from __future__ import annotations
 
@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.asr.rnnt_loss import rnnt_loss_from_logprobs
 from repro.asr.specaugment import SpecAugmentConfig, spec_augment
+from repro.kernels import ops
 from repro.models.layers import dense_init, embed_init
 from repro.models.lstm import LSTMConfig, lstm_stack, lstm_stack_init, lstm_stack_init_state, lstm_stack_step
 
@@ -35,7 +36,7 @@ class RNNTConfig:
     specaug: SpecAugmentConfig = dataclasses.field(default_factory=SpecAugmentConfig)
     dtype: str = "float32"
     param_dtype: str = "float32"
-    use_kernel: bool = False       # fused Pallas joint (interpret on CPU)
+    use_kernel: bool = False       # chooses nothing: the joint is chosen by _joint_kernel_path
     loss_norm: bool = True         # per-label-token NLL normalization
     scan_unroll: int = 1           # LSTM scan unroll (weight amortization)
     scan_chunk: int = 0            # time-chunked remat scan (grad-buffer traffic)
@@ -85,17 +86,30 @@ def predict(cfg: RNNTConfig, params, labels):
     return out
 
 
+def _joint_inputs(params, enc, pred, labels):
+    """The joint's projections e (B, T, J), g (B, U1, J) in the compute
+    dtype, and the label id of each of the U1 slots (the last is 0)."""
+    e = enc @ params["joint_enc"].astype(enc.dtype)
+    g = pred @ params["joint_pred"].astype(pred.dtype)
+    lbl = jnp.concatenate([labels, jnp.zeros((labels.shape[0], 1), labels.dtype)], axis=1)
+    return e, g, lbl
+
+
 def joint_logprobs_ref(cfg: RNNTConfig, params, enc, pred, labels, u_chunk: int = 8):
     """(blank_lp, label_lp): (B, T, U1) each, never materializing
-    (B, T, U1, V) — scans over U1 in chunks (jnp oracle of the kernel)."""
-    B, T, _ = enc.shape
-    U1 = pred.shape[1]
-    e = enc @ params["joint_enc"].astype(enc.dtype)             # (B, T, J)
-    g = pred @ params["joint_pred"].astype(pred.dtype)          # (B, U1, J)
-    w = params["joint_out"].astype(enc.dtype)
-    b = params["joint_bias"].astype(jnp.float32)
-    lbl = jnp.concatenate([labels, jnp.zeros((B, 1), labels.dtype)], axis=1)  # (B, U1)
+    (B, T, U1, V): the joint's projections, then ``joint_chunked``."""
+    e, g, lbl = _joint_inputs(params, enc, pred, labels)
+    return joint_chunked(e, g, params["joint_out"].astype(enc.dtype),
+                         params["joint_bias"].astype(jnp.float32), lbl, u_chunk)
 
+
+def joint_chunked(e, g, w, b, lbl, u_chunk: int = 8):
+    """The joint's (blank, label) log-probs from its projections e (B, T, J)
+    and g (B, U1, J), w (J, V), b (V,) and the label ids (B, U1): a scan
+    over U1 in chunks, each chunk's logits rebuilt in the backward (the
+    CPU path, and the jnp oracle of the kernel)."""
+    B, T, _ = e.shape
+    U1 = g.shape[1]
     n_chunks = max(1, U1 // u_chunk)
     pad = (-U1) % n_chunks
     if pad:
@@ -134,6 +148,12 @@ def joint_logits(cfg: RNNTConfig, params, enc_t, pred_u):
         params["joint_bias"].astype(jnp.float32)
 
 
+def _joint_kernel_path() -> bool:
+    """The joint runs the fused Pallas kernel off the CPU; on the CPU,
+    ``joint_logprobs_ref``."""
+    return jax.default_backend() != "cpu"
+
+
 def loss_fn(cfg: RNNTConfig, params, batch, rng=None):
     """batch: features (B,T,F), labels (B,U), frame_len (B,), label_len (B,),
     optional weight (B,). Returns (mean loss, aux).
@@ -150,20 +170,15 @@ def loss_fn(cfg: RNNTConfig, params, batch, rng=None):
         enc = encode(cfg, params, feats)
     with jax.named_scope("prednet"):
         pred = predict(cfg, params, batch["labels"])
+    frame_len = jnp.maximum(batch["frame_len"] // cfg.time_stride, 1)
     with jax.named_scope("joint"):
-        if cfg.use_kernel:
-            from repro.kernels.ops import rnnt_joint
-            e = enc @ params["joint_enc"].astype(enc.dtype)
-            g = pred @ params["joint_pred"].astype(pred.dtype)
-            lbl = jnp.concatenate(
-                [batch["labels"], jnp.zeros((batch["labels"].shape[0], 1), batch["labels"].dtype)],
-                axis=1)
-            blank_lp, label_lp = rnnt_joint(
-                e, g, params["joint_out"], params["joint_bias"], lbl)
+        if _joint_kernel_path():
+            e, g, lbl = _joint_inputs(params, enc, pred, batch["labels"])
+            blank_lp, label_lp = ops.rnnt_joint(e, g, params["joint_out"], params["joint_bias"],
+                                                lbl, frame_len)
         else:
             blank_lp, label_lp = joint_logprobs_ref(cfg, params, enc, pred, batch["labels"])
     with jax.named_scope("rnnt_loss"):
-        frame_len = jnp.maximum(batch["frame_len"] // cfg.time_stride, 1)
         nll = rnnt_loss_from_logprobs(blank_lp, label_lp, frame_len, batch["label_len"])
         if cfg.loss_norm:
             nll = nll / jnp.maximum(batch["label_len"].astype(jnp.float32), 1.0)

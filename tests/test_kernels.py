@@ -8,7 +8,7 @@ from repro.kernels import ref
 from repro.kernels.decode_attention import flash_decode
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.lstm_gates import lstm_gates_fused
-from repro.kernels.rnnt_joint import rnnt_joint_fused
+from repro.kernels.rnnt_joint import joint_forward, rnnt_joint
 
 def _rng():
     return np.random.default_rng(1234)
@@ -157,52 +157,6 @@ def test_flash_decode_sweep(B, S, H, Kv, D, window, pos, dtype):
                                atol=TOL[dtype], rtol=TOL[dtype])
 
 
-@pytest.mark.parametrize(
-    "B,T,U1,J,V,tq,tu,tv",
-    [
-        (2, 32, 16, 24, 64, 16, 8, 32),
-        (1, 16, 8, 16, 128, 8, 4, 64),
-        (2, 24, 12, 8, 48, 8, 4, 16),
-        (1, 64, 8, 32, 256, 16, 8, 128),
-    ],
-)
-def test_rnnt_joint_sweep(B, T, U1, J, V, tq, tu, tv):
-    e = _rand((B, T, J), jnp.float32)
-    g = _rand((B, U1, J), jnp.float32)
-    w = _rand((J, V), jnp.float32) * 0.3
-    b = _rand((V,), jnp.float32) * 0.1
-    lbl = jnp.asarray(np.random.default_rng(7).integers(0, V, (B, U1)), jnp.int32)
-    blank, label = rnnt_joint_fused(e, g, w, b, lbl, tq=tq, tu=tu, tv=tv,
-                                    interpret=True)
-    blank_ref, label_ref = ref.rnnt_joint_ref(e, g, w, b, lbl)
-    np.testing.assert_allclose(np.asarray(blank), np.asarray(blank_ref), atol=3e-5)
-    np.testing.assert_allclose(np.asarray(label), np.asarray(label_ref), atol=3e-5)
-
-
-def test_rnnt_joint_custom_vjp_matches_ref_grad():
-    from repro.kernels.ops import rnnt_joint
-
-    B, T, U1, J, V = 2, 16, 8, 12, 32
-    e = _rand((B, T, J), jnp.float32)
-    g = _rand((B, U1, J), jnp.float32)
-    w = _rand((J, V), jnp.float32) * 0.3
-    b = _rand((V,), jnp.float32) * 0.1
-    lbl = jnp.asarray(np.random.default_rng(7).integers(0, V, (B, U1)), jnp.int32)
-
-    def f_kernel(e, g, w, b):
-        bb, ll = rnnt_joint(e, g, w, b, lbl)
-        return (bb * 1.3 + ll).sum()
-
-    def f_ref(e, g, w, b):
-        bb, ll = ref.rnnt_joint_ref(e, g, w, b, lbl)
-        return (bb * 1.3 + ll).sum()
-
-    gk = jax.grad(f_kernel, argnums=(0, 1, 2, 3))(e, g, w, b)
-    gr = jax.grad(f_ref, argnums=(0, 1, 2, 3))(e, g, w, b)
-    for a, bgrad in zip(gk, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(bgrad), atol=1e-4)
-
-
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("B,H,th", [(4, 512, 256), (1, 128, 128), (8, 1024, 512),
                                     (4, 1152, 128)])
@@ -318,108 +272,224 @@ def test_blockwise_attention_matches_kernel_oracle():
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5)
 
 
-# ----------------------------------------- fused RNN-T joint bwd (PR 10)
+# ------------------------------------------------------ fused RNN-T joint
 
 
-def _joint_case(B, T, U1, J, V, seed=0):
+def _joint_case(B, T, U1, J, V, seed=0, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
-    e = jnp.asarray(rng.standard_normal((B, T, J)), jnp.float32)
-    g = jnp.asarray(rng.standard_normal((B, U1, J)), jnp.float32)
+    e = jnp.asarray(rng.standard_normal((B, T, J)), dtype)
+    g = jnp.asarray(rng.standard_normal((B, U1, J)), dtype)
     w = jnp.asarray(rng.standard_normal((J, V)) * 0.3, jnp.float32)
     b = jnp.asarray(rng.standard_normal((V,)) * 0.1, jnp.float32)
     lbl = jnp.asarray(rng.integers(0, V, (B, U1)), jnp.int32)
     return e, g, w, b, lbl
 
 
+def _live(frames, T, U1):
+    """(B, T, U1) True at the frames each utterance holds."""
+    return np.arange(T)[None, :, None] < np.asarray(frames)[:, None, None]
+
+
+def _assert_scaled(got, want, atol, names=("de", "dg", "dw", "db")):
+    for name, a, r in zip(names, got, want):
+        denom = float(jnp.abs(r).max()) + 1e-30
+        np.testing.assert_allclose(np.asarray(a) / denom, np.asarray(r) / denom,
+                                   atol=atol, err_msg=name)
+
+
+# B, T, U1, J, V, tq, tv, frames: T and U1 off their tiles (tq, 16), and
+# frame counts that leave whole tiles past an utterance
+JOINT_SHAPES = [
+    (2, 32, 16, 24, 64, 8, 32, (32, 32)),
+    (1, 19, 13, 16, 128, 8, 64, (19,)),
+    (2, 24, 12, 8, 48, 8, 16, (24, 9)),
+    (3, 61, 21, 32, 256, 16, 128, (61, 17, 1)),
+]
+
+
+@pytest.mark.parametrize("B,T,U1,J,V,tq,tv,frames", JOINT_SHAPES)
+def test_rnnt_joint_sweep(B, T, U1, J, V, tq, tv, frames):
+    """The forward against the dense oracle at every frame an utterance
+    holds; the frames of tiles wholly past it read 0."""
+    e, g, w, b, lbl = _joint_case(B, T, U1, J, V, seed=T)
+    blank, label = rnnt_joint(e, g, w, b, lbl, jnp.asarray(frames), tq=tq, tv=tv,
+                              interpret=True)
+    assert blank.shape == label.shape == (B, T, U1)
+    blank_ref, label_ref = ref.rnnt_joint_ref(e, g, w, b, lbl)
+    live = _live(frames, T, U1)
+    np.testing.assert_allclose(np.where(live, blank, 0), np.where(live, blank_ref, 0), atol=3e-5)
+    np.testing.assert_allclose(np.where(live, label, 0), np.where(live, label_ref, 0), atol=3e-5)
+    for i, f in enumerate(frames):
+        past = -(-f // tq) * tq
+        assert not np.asarray(blank[i, past:]).any() and not np.asarray(label[i, past:]).any()
+
+
+def test_rnnt_joint_custom_vjp_matches_ref_grad():
+    """The kernel's gradient, every frame held, against autodiff of the
+    dense oracle."""
+    B, T, U1, J, V = 2, 16, 8, 12, 32
+    e, g, w, b, lbl = _joint_case(B, T, U1, J, V, seed=7)
+    frames = jnp.full((B,), T)
+
+    def f(fn):
+        def loss(e, g, w, b):
+            bb, ll = fn(e, g, w, b)
+            return (bb * 1.3 + ll).sum()
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(e, g, w, b)
+
+    gk = f(lambda *a: rnnt_joint(*a, lbl, frames, interpret=True))
+    gr = f(lambda *a: ref.rnnt_joint_ref(*a, lbl))
+    for a, bgrad in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(bgrad), atol=1e-4)
+
+
 def test_rnnt_joint_forward_lse_output():
-    e, g, w, b, lbl = _joint_case(2, 32, 16, 24, 64, seed=3)
-    _, _, lse = rnnt_joint_fused(e, g, w, b, lbl, tq=16, tu=8, tv=32,
-                                 interpret=True, return_lse=True)
+    """The forward's log-sum-exp, the backward's anchor, against the dense
+    one; 0 on the tile past the second utterance's 8 frames."""
+    B, T, U1, J, V = 2, 16, 16, 24, 64
+    e, g, w, b, lbl = _joint_case(B, T, U1, J, V, seed=3)
+    ids = jnp.stack([jnp.zeros_like(lbl), lbl], axis=1)
+    w_pick = jnp.moveaxis(w[:, ids], 0, -1)
+    _, _, lse = joint_forward(e, g, w, b, w_pick, b[ids], jnp.array([16, 8]), tq=8, tv=32,
+                              interpret=True)
     h = jnp.tanh(e[:, :, None, :] + g[:, None, :, :])
     lse_ref = jax.nn.logsumexp(h @ w + b, axis=-1)
-    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
-                               atol=3e-5)
+    np.testing.assert_allclose(np.asarray(lse[0]), np.asarray(lse_ref[0]), atol=3e-5)
+    np.testing.assert_allclose(np.asarray(lse[1, :8]), np.asarray(lse_ref[1, :8]), atol=3e-5)
+    assert not np.asarray(lse[1, 8:]).any()
 
 
 def test_joint_ref_chunked_multichunk_matches_dense():
-    """Regression: with more than one U-chunk the chunked reference used
-    to flatten (chunks, T, c) in the wrong axis order, scrambling the U
-    axis of both the forward and (through jax.vjp) the backward."""
-    from repro.kernels.ops import _joint_ref_chunked
+    """Regression: with more than one U chunk the chunked joint used to
+    flatten (chunks, T, c) in the wrong axis order, scrambling the U axis
+    of both the forward and (through autodiff) the backward."""
+    from repro.models.rnnt import joint_chunked
 
     e, g, w, b, lbl = _joint_case(2, 16, 24, 12, 48, seed=5)
-    cb, cl = _joint_ref_chunked(e, g, w, b, lbl, u_chunk=8)
+    cb, cl = joint_chunked(e, g, w, b, lbl, u_chunk=8)
     rb, rl = ref.rnnt_joint_ref(e, g, w, b, lbl)
     np.testing.assert_allclose(np.asarray(cb), np.asarray(rb), atol=3e-5)
     np.testing.assert_allclose(np.asarray(cl), np.asarray(rl), atol=3e-5)
+    cts = jax.random.normal(jax.random.PRNGKey(0), (2,) + cb.shape)
+    _, vjp_c = jax.vjp(lambda *a: joint_chunked(*a, lbl, u_chunk=8), e, g, w, b)
+    _, vjp_r = jax.vjp(lambda *a: ref.rnnt_joint_ref(*a, lbl), e, g, w, b)
+    _assert_scaled(vjp_c(tuple(cts)), vjp_r(tuple(cts)), atol=3e-5)
 
 
-@pytest.mark.parametrize(
-    "B,T,U1,J,V,tq,tu,tv",
-    [
-        (2, 32, 16, 24, 64, 16, 8, 32),   # multi u-tile, multi v-slab
-        (1, 16, 8, 16, 128, 8, 4, 64),
-        (2, 24, 12, 8, 48, 8, 4, 16),
-        (1, 64, 8, 32, 256, 16, 8, 128),
-    ],
-)
-def test_rnnt_joint_bwd_fused_matches_chunked_ref(B, T, U1, J, V, tq, tu, tv):
-    """The two backward kernels (dh/de/dg with vocab innermost, dW/db
-    with vocab outermost) against autodiff through the chunked jnp
-    joint, on the forward's own saved lse."""
-    from repro.kernels.ops import _joint_ref_chunked
-    from repro.kernels.rnnt_joint import rnnt_joint_bwd_fused
+@pytest.mark.parametrize("B,T,U1,J,V,tq,tv,frames", JOINT_SHAPES)
+def test_rnnt_joint_bwd_fused_matches_chunked_ref(B, T, U1, J, V, tq, tv, frames):
+    """The backward kernel against autodiff through the chunked jnp
+    joint, on cotangents that are 0 past each utterance's frames (as the
+    transducer loss gives them): de, dg, dW and db agree, and de is
+    exactly 0 at every frame past the utterance."""
+    from repro.models.rnnt import joint_chunked
 
     e, g, w, b, lbl = _joint_case(B, T, U1, J, V, seed=B * T)
     rng = np.random.default_rng(9)
-    dbl = jnp.asarray(rng.standard_normal((B, T, U1)), jnp.float32)
-    dlb = jnp.asarray(rng.standard_normal((B, T, U1)), jnp.float32)
-    _, _, lse = rnnt_joint_fused(e, g, w, b, lbl, tq=tq, tu=tu, tv=tv,
-                                 interpret=True, return_lse=True)
-    de, dg, dw, db = rnnt_joint_bwd_fused(e, g, w, b, lbl, lse, dbl, dlb,
-                                          tq=tq, tu=tu, tv=tv, interpret=True)
-    _, vjp = jax.vjp(lambda e_, g_, w_, b_: _joint_ref_chunked(e_, g_, w_, b_, lbl),
-                     e, g, w, b)
-    for name, a, r in zip(("de", "dg", "dw", "db"), (de, dg, dw, db),
-                          vjp((dbl, dlb))):
-        denom = float(jnp.abs(r).max()) + 1e-30
-        np.testing.assert_allclose(np.asarray(a) / denom,
-                                   np.asarray(r) / denom,
-                                   atol=5e-5, err_msg=name)
+    live = _live(frames, T, U1)
+    cts = tuple(jnp.asarray(rng.standard_normal((B, T, U1)) * live, jnp.float32) for _ in "bl")
+    _, vjp_k = jax.vjp(lambda *a: rnnt_joint(*a, lbl, jnp.asarray(frames), tq=tq, tv=tv,
+                                             interpret=True), e, g, w, b)
+    _, vjp_r = jax.vjp(lambda *a: joint_chunked(*a, lbl), e, g, w, b)
+    got = vjp_k(cts)
+    _assert_scaled(got, vjp_r(cts), atol=5e-5)
+    for i, f in enumerate(frames):
+        assert not np.asarray(got[0][i, f:]).any()
 
 
 @pytest.mark.parametrize("forced", [False, True], ids=["backend", "kernel"])
 def test_rnnt_joint_custom_vjp_pallas_dispatch_multichunk(monkeypatch, forced):
-    """End-to-end: ops.rnnt_joint's gradients match plain-jnp reference
-    grads with its backward chosen by backend (the chunked jnp
-    backward on the CPU) and with the Pallas backward forced through
-    the joint's backend predicate — on a multi-chunk U1 so the dispatch
-    covers the shape class the chunked path buckets."""
+    """``loss_fn`` on the smoke RNN-T with ragged frame and label counts
+    (U1 over one chunk): its joint chosen by backend (the chunked jnp path
+    on the CPU) and forced onto the kernel through ``_joint_kernel_path``
+    (interpret mode); the loss and every gradient agree with the jnp path."""
+    from repro.configs.rnnt_librispeech import make_smoke_config
     from repro.kernels import ops
+    from repro.models import rnnt
 
-    assert not ops._use_joint_bwd_pallas()
+    cfg = make_smoke_config()
+    params = rnnt.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    batch = {"features": jnp.asarray(rng.standard_normal((3, 37, cfg.feat_dim)), jnp.float32),
+             "labels": jnp.asarray(rng.integers(1, cfg.vocab, (3, 11)), jnp.int32),
+             "frame_len": jnp.array([37, 12, 5]), "label_len": jnp.array([11, 4, 0])}
+
+    def value_and_grad():  # traced anew at each call
+        return jax.jit(jax.value_and_grad(lambda p: rnnt.loss_fn(cfg, p, batch)[0]))(params)
+
+    want = value_and_grad()
+    assert not rnnt._joint_kernel_path()
     if forced:
-        monkeypatch.setattr(ops, "_use_joint_bwd_pallas", lambda: True)
-    pallas, calls = ops.rnnt_joint_bwd_fused, []
+        monkeypatch.setattr(rnnt, "_joint_kernel_path", lambda: True)
+    kernel, calls = ops.rnnt_joint, []
 
-    def spy(*args, **kwargs):
-        calls.append(kwargs)
-        return pallas(*args, **kwargs)
+    def spy(*args):
+        calls.append(args[-1].shape)
+        return kernel(*args)
 
-    monkeypatch.setattr(ops, "rnnt_joint_bwd_fused", spy)
-    e, g, w, b, lbl = _joint_case(2, 32, 24, 16, 64, seed=11)
-
-    def f(fn):
-        def loss(e, g, w, b):
-            bb, ll = fn(e, g, w, b, lbl)
-            return (bb * 1.3 + ll).sum()
-        return jax.grad(loss, argnums=(0, 1, 2, 3))(e, g, w, b)
-
-    gk = f(ops.rnnt_joint)
-    assert calls == ([{"interpret": True}] if forced else [])
-    gr = f(ref.rnnt_joint_ref)
-    for name, a, r in zip(("de", "dg", "dw", "db"), gk, gr):
+    monkeypatch.setattr(ops, "rnnt_joint", spy)
+    got = value_and_grad()
+    assert calls == ([(3,)] if forced else [])  # one frame count per utterance
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-6)
+    for a, r in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
         denom = float(jnp.abs(r).max()) + 1e-30
-        np.testing.assert_allclose(np.asarray(a) / denom,
-                                   np.asarray(r) / denom,
-                                   atol=1e-4, err_msg=name)
+        np.testing.assert_allclose(np.asarray(a) / denom, np.asarray(r) / denom, atol=1e-5)
+
+
+def test_rnnt_joint_vmapped_over_clients():
+    """vmap over K = 3 clients, each with its own weights and frame
+    counts, as the round runs the local step: the forward and the
+    gradient equal each client's own call of the dense oracle."""
+    K, B, T, U1, J, V = 3, 2, 20, 13, 16, 128
+    cases = [_joint_case(B, T, U1, J, V, seed=k) for k in range(K)]
+    e, g, w, b, lbl = (jnp.stack(x) for x in zip(*cases))
+    frames = jnp.array([[20, 3], [9, 20], [1, 16]])
+    live = np.stack([_live(f, T, U1) for f in np.asarray(frames)])
+
+    def loss(fn, live):
+        def f(e, g, w, b, lbl, frames):
+            bb, ll = fn(e, g, w, b, lbl, frames)
+            return jnp.sum((bb * 1.3 + ll) * live), (bb, ll)
+        return f
+
+    kern = lambda e, g, w, b, lbl, fr: rnnt_joint(e, g, w, b, lbl, fr, tv=64, interpret=True)
+    dense = lambda e, g, w, b, lbl, fr: ref.rnnt_joint_ref(e, g, w, b, lbl)
+    grads_k, (bk, lk) = jax.vmap(
+        lambda *a: jax.grad(loss(kern, a[-1]), argnums=(0, 1, 2, 3), has_aux=True)(*a[:-1])
+    )(e, g, w, b, lbl, frames, live)
+    for k in range(K):
+        grads_r, (br, lr) = jax.grad(loss(dense, live[k]), argnums=(0, 1, 2, 3), has_aux=True)(
+            e[k], g[k], w[k], b[k], lbl[k], frames[k])
+        np.testing.assert_allclose(np.where(live[k], bk[k], 0), np.where(live[k], br, 0), atol=3e-5)
+        np.testing.assert_allclose(np.where(live[k], lk[k], 0), np.where(live[k], lr, 0), atol=3e-5)
+        _assert_scaled([x[k] for x in grads_k], grads_r, atol=5e-5)
+
+
+def test_rnnt_joint_bf16_operands():
+    """In the configuration's compute type: bfloat16 e and g (the MXU's
+    operands, with Wo cast to bfloat16 inside), float32 log-probs and
+    parameter gradients, within bfloat16 rounding of the float32 oracle
+    on the same rounded inputs."""
+    B, T, U1, J, V = 2, 16, 13, 32, 256
+    e, g, w, b, lbl = _joint_case(B, T, U1, J, V, seed=2, dtype=jnp.bfloat16)
+    frames = jnp.array([16, 11])
+    live = _live(frames, T, U1)
+
+    def loss(fn):
+        def f(e, g, w, b):
+            bb, ll = fn(e, g, w, b)
+            return jnp.sum((bb * 1.3 + ll) * live)
+        return f
+
+    kern = lambda *a: rnnt_joint(*a, lbl, frames, tv=128, interpret=True)
+    blank, label = kern(e, g, w, b)
+    assert blank.dtype == label.dtype == jnp.float32
+    w16 = w.astype(jnp.bfloat16).astype(jnp.float32)
+    rb, rl = ref.rnnt_joint_ref(e.astype(jnp.float32), g.astype(jnp.float32), w16, b, lbl)
+    np.testing.assert_allclose(np.where(live, blank, 0), np.where(live, rb, 0), atol=3e-2)
+    np.testing.assert_allclose(np.where(live, label, 0), np.where(live, rl, 0), atol=3e-2)
+    gk = jax.grad(loss(kern), argnums=(0, 1, 2, 3))(e, g, w, b)
+    assert [x.dtype for x in gk] == [jnp.bfloat16, jnp.bfloat16, jnp.float32, jnp.float32]
+    gr = jax.grad(loss(lambda *a: ref.rnnt_joint_ref(*a, lbl)), argnums=(0, 1, 2, 3))(
+        e.astype(jnp.float32), g.astype(jnp.float32), w16, b)
+    _assert_scaled([x.astype(jnp.float32) for x in gk], gr, atol=3e-2)

@@ -14,7 +14,9 @@ compilation cache is off around these compiles: an entry compiled for
 a described chip cannot be read back without one.
 """
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -154,3 +156,87 @@ def test_encoder_kernels_count_to_enc_attention(one_chip, monkeypatch):
     calls = kernels.custom_calls(text)
     assert len(calls) == 3
     assert {scopes_encdec.stage(names[c]) for c in calls} == {"enc_attention"}
+
+
+# (K, B, T', U+1) of the RNN-T cells' joint: clients, utterances, encoder
+# frames, label slots; J 640, V 4096
+JOINT_CELLS = {"rnnt-libri-fp32": (4, 4, 1000, 61), "rnnt-query-fp32": (8, 4, 200, 13)}
+
+
+@functools.lru_cache(maxsize=None)
+def _joint_texts(sharding, cell):
+    """Compiled text of the joint's forward and of its gradient, each
+    vmapped over the cell's clients, as the round's local step runs them."""
+    from repro.kernels.rnnt_joint import rnnt_joint
+
+    K, B, T, U1 = JOINT_CELLS[cell]
+    J, V = 640, 4096
+    args = (
+        _spec((K, B, T, J), jnp.bfloat16, sharding),
+        _spec((K, B, U1, J), jnp.bfloat16, sharding),
+        _spec((K, J, V), jnp.float32, sharding),
+        _spec((K, V), jnp.float32, sharding),
+        _spec((K, B, U1), jnp.int32, sharding),
+        _spec((K, B), jnp.int32, sharding),
+    )
+
+    def loss(*a):
+        blank, label = rnnt_joint(*a)
+        return jnp.sum(blank * 1.3 + label)
+
+    fwd = jax.jit(jax.vmap(rnnt_joint)).lower(*args).compile().as_text()
+    grad = jax.jit(jax.vmap(jax.grad(loss, argnums=(0, 1, 2, 3)))).lower(*args).compile().as_text()
+    return fwd, grad
+
+
+@pytest.mark.parametrize("cell", sorted(JOINT_CELLS))
+def test_rnnt_joint_compiles_vmapped_over_clients(one_chip, cell):
+    """The joint kernel at the cell's shapes (T' and U+1 off their tiles):
+    one call forward; the forward and the one backward kernel in the
+    gradient."""
+    fwd, grad = _joint_texts(one_chip, cell)
+    assert fwd.count('custom_call_target="tpu_custom_call"') == 1
+    assert grad.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_rnnt_joint_vmapped_kernel_in_no_client_loop(one_chip):
+    """vmap over clients adds a grid axis to each kernel: the compiled
+    gradient holds no loop of calls (the frame counts are an ordinary
+    input, not scalar prefetch, which vmap would turn into a loop)."""
+    for cell in JOINT_CELLS:
+        fwd, grad = _joint_texts(one_chip, cell)
+        assert not re.search(r"\bwhile\(", fwd + grad)
+
+
+def test_joint_kernels_count_to_joint_loss(one_chip, monkeypatch):
+    """The RNN-T's loss at its published widths (one LSTM layer each
+    side), its gradient compiled for the chip: the joint's two kernel
+    calls carry the ``joint`` scope, so the benchmark books them to
+    ``joint_loss_ms`` and ``joint_kernel_pct``; the gate kernels stay in
+    the LSTMs' stages."""
+    import dataclasses
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from chipbench import kernels, scopes
+    from repro.configs import get_arch
+    from repro.models import rnnt
+
+    cfg = dataclasses.replace(get_arch("rnnt-librispeech").make_config(), enc_layers=1,
+                              pred_layers=1)
+    params = jax.eval_shape(lambda: rnnt.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), params)
+    B, T, U = 4, 64, 12
+    batch = {"features": _spec((B, T, cfg.feat_dim), jnp.float32, one_chip),
+             "labels": _spec((B, U), jnp.int32, one_chip),
+             "frame_len": _spec((B,), jnp.int32, one_chip),
+             "label_len": _spec((B,), jnp.int32, one_chip)}
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = jax.jit(jax.grad(lambda p, b: rnnt.loss_fn(cfg, p, b)[0])).lower(params, batch) \
+        .compile().as_text()
+    names = scopes.op_scopes(text)
+    stages = [scopes.stage(names[c]) for c in kernels.custom_calls(text)]
+    assert stages.count("joint_loss") == 2
+    assert set(stages) == {"joint_loss", "encoder_fwd", "encoder_bwd", "prednet"}
